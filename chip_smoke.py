@@ -1,0 +1,233 @@
+"""Smoke run on the TPU through the repo's own entry points.
+
+    python chip_smoke.py               # one chip
+    python chip_smoke.py --four-chips  # sharded training on a 2x2 host only
+
+One chip: serves qwen3-0.6b at its published widths (random weights from a
+seed) through ``serve_real_model``, the function ``repro.launch.serve`` runs:
+16 streams on 8 slots, so the streams past the slots wait for admission and
+are served in a second wave, with 1024-token prompts and 32 generated
+tokens.  It checks that every token is in the
+vocabulary, that a second ``generate`` gives the same tokens, and that the
+last-position logits of ``prefill`` match the cache-free pass of
+``forward_train``.  Then it runs each Pallas kernel natively at the widths of
+``repro.kernels.cases`` against its ``ref.py`` oracle.
+
+``--four-chips``: three steps of qwen3-0.6b training at full width, built as
+``repro.launch.train`` builds them, on a (data=2, model=2) mesh and then on
+one chip, with the per-step losses compared and the parameter bytes per
+device read from ``memory_analysis()``.
+
+Every phase prints its wall time and, apart, the seconds spent compiling and
+the persistent-cache hits.  Any failed check or phase raises, so the exit
+code is non-zero; on success the last line is one JSON object naming the
+device.  Without a TPU it exits non-zero before any phase.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+from contextlib import contextmanager
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
+
+# bf16 tolerances, fixed before the first chip run
+LOGIT_TOL = 2e-2     # max |prefill - forward| over max |forward| logit
+KERNEL_TOL = 2e-2    # max |kernel - oracle| over max |oracle| output
+LOSS_RTOL = 1e-2     # |sharded - one-chip| loss over one-chip loss, per step
+
+
+class Clock:
+    """Wall time per phase, with compile seconds and cache hits kept apart
+    (from JAX's monitoring events)."""
+
+    def __init__(self, jax):
+        self.compile_s = 0.0
+        self.cache_hits = 0
+
+        def on_duration(event, duration_secs, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                self.compile_s += duration_secs
+
+        def on_event(event, **_):
+            if event == "/jax/compilation_cache/cache_hits":
+                self.cache_hits += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+
+    @contextmanager
+    def phase(self, name):
+        c0, h0 = self.compile_s, self.cache_hits
+        t0 = time.perf_counter()
+        yield
+        wall = time.perf_counter() - t0
+        print(f"phase {name}: wall_s={wall:.3f} "
+              f"compile_s={self.compile_s - c0:.3f} "
+              f"cache_hits={self.cache_hits - h0}", flush=True)
+
+
+def _rel_err(got, want):
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
+
+
+def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
+                  gen_len):
+    import jax
+    import numpy as np
+
+    from repro.launch.serve import serve_real_model
+    from repro.models import forward_hidden
+
+    with clock.phase("serve_first_generate"):
+        eng, prompts, tokens = serve_real_model(
+            arch, smoke=smoke, streams=streams, slots=slots,
+            prompt_len=prompt_len, gen_len=gen_len)
+    cfg = eng.cfg
+    print(f"serve: arch={cfg.name} d_model={cfg.d_model} "
+          f"layers={cfg.n_layers} vocab={cfg.vocab_size} streams={streams} "
+          f"slots={slots} prompt_len={prompt_len} gen_len={gen_len} "
+          f"parked={getattr(eng.admission, 'stat_parked', 0)}")
+    if tokens.shape != (streams, gen_len):
+        raise AssertionError(f"tokens shape {tokens.shape}")
+    if not ((tokens >= 0) & (tokens < cfg.vocab_size)).all():
+        raise AssertionError("a generated token is outside the vocabulary")
+
+    with clock.phase("serve_second_generate"):
+        again = eng.generate(prompts, gen_len=gen_len)
+    if not np.array_equal(tokens, again):
+        raise AssertionError(
+            f"second generate differs in {(tokens != again).sum()} tokens")
+
+    with clock.phase("prefill_vs_forward"):
+        batch = {"tokens": jax.numpy.asarray(prompts[:slots])}
+        logits, _ = eng.prefill_fn(eng.params, batch)
+        ref = jax.jit(lambda p, b: forward_hidden(cfg, p, b, remat=False)[0]
+                      [:, -1:] @ p["lm_head"])(eng.params, batch)
+        err = _rel_err(logits, ref)
+    print(f"serve: prefill vs forward max_rel_err={err:.3e} "
+          f"(tol {LOGIT_TOL:g}) first tokens={tokens[0, :8].tolist()}")
+    if not err <= LOGIT_TOL:
+        raise AssertionError(f"prefill logits off by {err:.3e}")
+
+
+def check_kernels(clock, cases, impl="pallas"):
+    import jax
+
+    for i, case in enumerate(cases):
+        args = case.sample(jax.random.key(i))
+        with clock.phase(f"kernel_{case.name}"):
+            got = jax.block_until_ready(
+                jax.jit(lambda *a: case.op(*a, impl=impl))(*args))
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(case.ref)(*args)
+        got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+        errs = [_rel_err(g, w) for g, w in zip(got, want)]
+        print(f"kernel {case.name}: shapes={[s for s, _ in case.shapes]} "
+              f"max_rel_err={max(errs):.3e} (tol {KERNEL_TOL:g})")
+        if not max(errs) <= KERNEL_TOL:
+            raise AssertionError(f"{case.name} off its oracle by {errs}")
+
+
+def check_sharded_training(clock, cfg, devices, *, model, batch, seq, steps):
+    """Losses of ``steps`` train steps on a (data, model) mesh over
+    ``devices`` against the same steps on ``devices[0]`` alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.config import OptimizerConfig
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import build_sharded_train
+
+    opt_cfg = OptimizerConfig(warmup_steps=max(steps // 10, 1),
+                              total_steps=steps)
+    k1, k2 = jax.random.split(jax.random.key(1))
+    data = {"tokens": jax.random.randint(k1, (batch, seq), 0, cfg.vocab_size),
+            "targets": jax.random.randint(k2, (batch, seq), 0,
+                                          cfg.vocab_size)}
+
+    def run(name, devs, model_axis):
+        mesh = make_host_mesh(model=model_axis, devices=devs)
+        with clock.phase(f"train_{name}"):
+            step_fn, params, opt = build_sharded_train(cfg, mesh, opt_cfg)
+            compiled = step_fn.lower(params, opt, data, jnp.int32(0)).compile()
+            losses = []
+            for i in range(steps):
+                params, opt, metrics = compiled(params, opt, data,
+                                                jnp.int32(i))
+                losses.append(float(metrics["loss"]))
+        per_dev = {}
+        for leaf in jax.tree.leaves(params):
+            for shard in leaf.addressable_shards:
+                per_dev[shard.device.id] = (per_dev.get(shard.device.id, 0)
+                                            + shard.data.nbytes)
+        mem = compiled.memory_analysis()
+        print(f"train {name}: mesh={dict(mesh.shape)} losses={losses} "
+              f"param_bytes_per_device={per_dev} "
+              f"argument_bytes={mem.argument_size_in_bytes} "
+              f"temp_bytes={mem.temp_size_in_bytes} "
+              f"output_bytes={mem.output_size_in_bytes}")
+        del params, opt
+        return losses, mem.argument_size_in_bytes, per_dev
+
+    sharded, arg_sharded, dev_sharded = run("sharded", devices, model)
+    single, arg_single, _ = run("one_chip", devices[:1], 1)
+    for i, (a, b) in enumerate(zip(sharded, single)):
+        if not abs(a - b) <= LOSS_RTOL * abs(b):
+            raise AssertionError(f"step {i}: sharded loss {a} vs {b}")
+    if len(dev_sharded) != len(devices) or \
+            max(dev_sharded.values()) > 0.75 * sum(dev_sharded.values()):
+        raise AssertionError(f"parameters not split: {dev_sharded}")
+    if not arg_sharded < 0.75 * arg_single:
+        raise AssertionError(f"sharded step takes {arg_sharded} argument "
+                             f"bytes per device vs {arg_single} on one")
+    print(f"train: losses agree within {LOSS_RTOL:g}; argument bytes per "
+          f"device {arg_sharded} sharded vs {arg_single} on one chip")
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--four-chips", action="store_true",
+                    help="run only the sharded-training phase on 4 chips")
+    args = ap.parse_args()
+
+    from repro.launch.compile_cache import use_compile_cache
+    cache_dir = use_compile_cache()
+
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    print(f"device: platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(devices)} compile_cache={cache_dir}", flush=True)
+    if dev.platform != "tpu":
+        sys.exit(f"no TPU found: JAX's devices are {dev.platform}; "
+                 "this smoke run is for the chip only")
+
+    from repro.configs import get_config
+    from repro.kernels.cases import real_width_cases
+
+    clock = Clock(jax)
+    if args.four_chips:
+        if len(devices) < 4:
+            sys.exit(f"--four-chips needs 4 TPU chips, found {len(devices)}")
+        check_sharded_training(clock, get_config("qwen3-0.6b"), devices[:4],
+                               model=2, batch=4, seq=256, steps=3)
+    else:
+        check_serving(clock, "qwen3-0.6b", smoke=False, streams=16, slots=8,
+                      prompt_len=1024, gen_len=32)
+        check_kernels(clock, real_width_cases())
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devices)}}))
+
+
+if __name__ == "__main__":
+    main()

@@ -288,7 +288,9 @@ class HardwareSpec:
     hbm_bw: float = 819e9            # bytes/s per chip
     ici_bw: float = 50e9             # bytes/s per link
     hbm_bytes: float = 16e9
-    vmem_bytes: float = 128 * 2**20  # 128 MiB VMEM per chip
+    # 128 MiB of VMEM per chip, but the TPU compiler (libtpu 0.0.34) holds
+    # each Pallas kernel to a scoped limit of 16 MiB of it
+    vmem_bytes: float = 128 * 2**20
 
 
 V5E = HardwareSpec()

@@ -1,3 +1,23 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels, each as ``kernel.py`` + ``ops.py`` + ``ref.py``.
+
+Every op takes ``impl``: ``pallas`` runs the kernel natively, ``interpret``
+runs it in the Pallas interpreter (how the tests check it on a CPU), ``ref``
+runs the plain oracle, and ``auto`` means ``pallas`` on a TPU and is an
+error anywhere else, so no caller falls back to the interpreter unawares.
+"""
+
+import jax
+
+
+def interpret_mode(impl: str) -> bool:
+    """The ``interpret`` flag of a ``pallas_call`` for ``impl``."""
+    if impl == "auto":
+        backend = jax.default_backend()
+        if backend != "tpu":
+            raise RuntimeError(
+                f"impl='auto' runs Pallas kernels on a TPU only, and the "
+                f"backend is {backend!r}; pass impl='interpret' or 'ref'")
+        return False
+    if impl not in ("pallas", "interpret"):
+        raise ValueError(f"unknown kernel impl {impl!r}")
+    return impl == "interpret"

@@ -1,14 +1,13 @@
 """Public op: flash attention with kernel/reference dispatch.
 
-On TPU the Pallas kernel runs natively; on CPU (this container) the kernel
-is validated in ``interpret=True`` mode against ``ref.attention_ref``
-(tests/test_kernels.py sweeps shapes and dtypes).
+On a TPU the Pallas kernel runs natively; on a CPU the tests run it with
+``impl="interpret"`` against ``ref.attention_ref`` (tests/test_kernels.py
+sweeps shapes and dtypes).
 """
 
 from __future__ import annotations
 
-import jax
-
+from .. import interpret_mode
 from .kernel import flash_attention_fwd
 from .ref import attention_ref
 
@@ -18,7 +17,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     """impl: auto | pallas | interpret | ref."""
     if impl == "ref":
         return attention_ref(q, k, v, causal=causal, window=window)
-    if impl == "auto":
-        impl = ("pallas" if jax.default_backend() == "tpu" else "interpret")
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                               interpret=(impl == "interpret"))
+                               interpret=interpret_mode(impl))

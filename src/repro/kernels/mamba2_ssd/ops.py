@@ -2,8 +2,7 @@
 
 from __future__ import annotations
 
-import jax
-
+from .. import interpret_mode
 from .kernel import ssd_fwd
 from .ref import ssd_ref
 
@@ -12,7 +11,5 @@ def ssd(xdt, a, Bm, Cm, *, chunk: int = 128, impl: str = "auto"):
     """impl: auto | pallas | interpret | ref."""
     if impl == "ref":
         return ssd_ref(xdt, a, Bm, Cm, chunk=chunk)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
     return ssd_fwd(xdt, a, Bm, Cm, chunk=chunk,
-                   interpret=(impl == "interpret"))
+                   interpret=interpret_mode(impl))

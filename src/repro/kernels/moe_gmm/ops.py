@@ -2,8 +2,7 @@
 
 from __future__ import annotations
 
-import jax
-
+from .. import interpret_mode
 from .kernel import gmm
 from .ref import gmm_ref
 
@@ -12,6 +11,4 @@ def grouped_matmul(x, w, *, impl: str = "auto"):
     """impl: auto | pallas | interpret | ref."""
     if impl == "ref":
         return gmm_ref(x, w)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
-    return gmm(x, w, interpret=(impl == "interpret"))
+    return gmm(x, w, interpret=interpret_mode(impl))

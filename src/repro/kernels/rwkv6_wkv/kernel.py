@@ -7,6 +7,9 @@ intra-chunk quadratic form is evaluated through decay-scaled r~/k~ factors
 (kept in f32; chunk=16 bounds the within-chunk decay range so the factors
 stay representable - see models/rwkv6.py MAX_DECAY_RATE).
 
+Mosaic lowers no cumsum: the decay prefix sums are products with a
+triangular ones matrix at HIGHEST precision.
+
 Per (batch*head, chunk) program:
   r,k,v,w tiles (Q, P);  state (P, P) f32 scratch;
   scores = tril(r~ @ k~^T, -1) + bonus diag; y = scores @ v + r~ @ state.
@@ -33,6 +36,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_out_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
     r = r_ref[...].astype(f32)                  # (Q, P)
     k = k_ref[...].astype(f32)
     v = v_ref[...].astype(f32)
@@ -40,10 +44,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_out_ref,
     u = u_ref[...].astype(f32)                  # (1, P)
 
     logw = jnp.log(jnp.maximum(w, 1e-8))
-    cum = jnp.cumsum(logw, axis=0)              # inclusive (Q, P)
+    # inclusive cumsum over the chunk (Mosaic has no cumsum): tril @ logw
+    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    cum = jax.lax.dot_general(
+        (li >= lj).astype(f32), logw, (((1,), (0,)), ((), ())),
+        precision=hi, preferred_element_type=f32)        # (Q, P)
     b_incl = jnp.exp(cum)
     b_excl = jnp.exp(cum - logw)
-    b_last = jnp.exp(cum[-1])                   # (P,)
+    # whole-chunk decay per k channel, as a column: logw^T @ ones
+    b_last = jnp.exp(jax.lax.dot_general(
+        logw, jnp.ones((chunk, 1), f32), (((0,), (0,)), ((), ())),
+        precision=hi, preferred_element_type=f32))       # (P, 1)
 
     r_t = r * b_excl
     k_t = k / jnp.maximum(b_incl, 1e-37)
@@ -51,15 +63,13 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_out_ref,
     scores = jax.lax.dot_general(
         r_t, k_t, (((1,), (1,)), ((), ())),
         preferred_element_type=f32)             # (Q, Q)
-    li = jax.lax.iota(jnp.int32, chunk)
-    strict_tril = li[:, None] > li[None, :]
-    scores = jnp.where(strict_tril, scores, 0.0)
-    diag = jnp.sum(r * u * k, axis=1)           # (Q,) bonus for j == i
+    scores = jnp.where(li > lj, scores, 0.0)    # strictly lower
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)  # (Q, 1) bonus, j == i
 
     y = jax.lax.dot_general(
         scores, v, (((1,), (0,)), ((), ())),
         preferred_element_type=f32)
-    y = y + diag[:, None] * v
+    y = y + diag * v
 
     state = state_ref[...]                      # (P, P) [k_dim, v_dim]
     y = y + jax.lax.dot_general(
@@ -69,7 +79,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_out_ref,
     upd = jax.lax.dot_general(
         k_t, v, (((0,), (0,)), ((), ())),
         preferred_element_type=f32)             # (P, P)
-    new_state = (state + upd) * b_last[:, None]
+    new_state = (state + upd) * b_last
     state_ref[...] = new_state
 
     y_ref[...] = y.astype(y_ref.dtype)

@@ -2,8 +2,7 @@
 
 from __future__ import annotations
 
-import jax
-
+from .. import interpret_mode
 from .kernel import wkv_fwd
 from .ref import wkv_ref
 
@@ -12,7 +11,5 @@ def wkv(r, k, v, w, u, *, chunk: int = 16, impl: str = "auto"):
     """impl: auto | pallas | interpret | ref."""
     if impl == "ref":
         return wkv_ref(r, k, v, w, u, chunk=chunk)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
     return wkv_fwd(r, k, v, w, u, chunk=chunk,
-                   interpret=(impl == "interpret"))
+                   interpret=interpret_mode(impl))

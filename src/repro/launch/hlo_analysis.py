@@ -54,20 +54,9 @@ _ELEMENTWISE_HINT = {"add", "subtract", "multiply", "divide", "maximum",
 
 
 def normalize_cost_analysis(cost) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across jaxlib versions.
-
-    Older jaxlib returned a one-element list of per-program dicts; newer
-    versions return the dict directly (and may return ``None`` for programs
-    with no analysis).  Multi-element lists are summed key-wise."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        merged: Dict[str, float] = {}
-        for d in cost:
-            for k, v in (d or {}).items():
-                merged[k] = merged.get(k, 0.0) + float(v)
-        return merged
-    return {k: float(v) for k, v in dict(cost).items()}
+    """``Compiled.cost_analysis()`` as floats; ``{}`` for a program XLA
+    gives no analysis (``None``)."""
+    return {k: float(v) for k, v in (cost or {}).items()}
 
 
 def shape_bytes(txt: str) -> int:

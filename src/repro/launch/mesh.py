@@ -12,21 +12,32 @@ Axes:
 The ``pod`` axis is the slow (DCN/inter-pod ICI) dimension: gradient sync is
 hierarchical - reduce-scatter on ``data`` inside a pod, all-reduce of the
 small shards across ``pod``, all-gather back on ``data``.
+
+Every axis is ``AxisType.Auto``: ``ShardingRules`` places arrays with
+``NamedSharding`` and sharding constraints and leaves propagation to GSPMD.
+``jax.make_mesh`` otherwise makes explicit axes, under which ops such as the
+embedding gather must be told their output sharding.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_host_mesh(model: int = 1):
-    """Small mesh over whatever devices exist (tests / examples on CPU)."""
-    n = len(jax.devices())
-    data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+def make_host_mesh(model: int = 1, devices=None):
+    """(data, model) mesh over ``devices`` (default: every local device)."""
+    devices = jax.devices() if devices is None else list(devices)
+    return _auto_mesh((len(devices) // model, model), ("data", "model"),
+                      devices)

@@ -1,7 +1,8 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Runs the continuous-batching engine with GCR admission over a (reduced)
-model, or the virtual-time fleet engine for capacity planning sweeps.
+Runs the real-model engine with GCR admission over a model at its published
+widths (``--smoke``: the reduced config) with random weights from ``--seed``,
+or the virtual-time fleet engine for capacity planning sweeps.
 """
 
 from __future__ import annotations
@@ -12,15 +13,37 @@ import jax
 import numpy as np
 
 from ..cluster import ROUTERS, WORKLOADS
-from ..configs import ARCHS, get_smoke_config
+from ..configs import ARCHS, get_config, get_smoke_config
 from ..models import init_params
 from ..serving.engine import (JaxServeEngine, Request, SimServeEngine,
                               make_admission)
+from .compile_cache import use_compile_cache
+
+
+def serve_real_model(arch: str, *, smoke: bool, streams: int, slots: int,
+                     prompt_len: int, gen_len: int, admission: str = "gcr",
+                     seed: int = 0):
+    """Greedy-decode ``gen_len`` tokens for ``streams`` random prompts on a
+    ``slots``-lane ``JaxServeEngine``; streams past the slots are parked by
+    admission and served as lanes free.  Weights and prompts come from
+    ``seed``.  Returns (engine, prompts (streams, prompt_len), tokens
+    (streams, gen_len))."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    params = init_params(cfg, jax.random.key(seed))
+    eng = JaxServeEngine(cfg, params, n_slots=slots,
+                         max_len=prompt_len + gen_len + 4,
+                         admission_kind=admission)
+    prompts = np.random.default_rng(seed).integers(
+        0, cfg.vocab_size, (streams, prompt_len)).astype(np.int32)
+    return eng, prompts, eng.generate(prompts, gen_len=gen_len)
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=ARCHS)
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced config (CPU-friendly)")
     ap.add_argument("--admission", default="gcr",
                     choices=["none", "gcr", "gcr_pod"])
     ap.add_argument("--streams", type=int, default=8)
@@ -169,15 +192,11 @@ def main() -> None:
                   f"{res.p50_latency_ms:>8.0f} {res.completed:>6}")
         return
 
-    cfg = get_smoke_config(args.arch)
-    params = init_params(cfg, jax.random.key(0))
-    eng = JaxServeEngine(cfg, params, n_slots=args.slots,
-                         max_len=args.prompt_len + args.gen_len + 4,
-                         admission_kind=args.admission)
-    prompts = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (args.streams, args.prompt_len)).astype(np.int32)
-    out = eng.generate(prompts, gen_len=args.gen_len)
-    print(f"arch={cfg.name} streams={args.streams} slots={args.slots} "
+    eng, _, out = serve_real_model(
+        args.arch, smoke=args.smoke, streams=args.streams, slots=args.slots,
+        prompt_len=args.prompt_len, gen_len=args.gen_len,
+        admission=args.admission, seed=args.seed)
+    print(f"arch={eng.cfg.name} streams={args.streams} slots={args.slots} "
           f"admission={args.admission}")
     print(f"fast admits: {eng.admission.stat_fast}  "
           f"parked: {getattr(eng.admission, 'stat_parked', 0)}")

@@ -7,14 +7,14 @@ production loop: GCR-locked prefetch pipeline -> jitted sharded train step
 resume from the latest checkpoint.
 
 On a real TPU pod this same entry point runs under the usual multi-host
-launcher (one process per host; `jax.distributed.initialize` is called when
-the standard TPU env vars are present); on CPU it runs the reduced configs.
+launcher (one process per host; ``jax.distributed.initialize`` is called
+when ``TPU_WORKER_HOSTNAMES`` lists more than one host); on one host it
+runs alone, and on a CPU it runs the reduced configs.
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import os
 import time
 
@@ -26,11 +26,37 @@ from ..config import OptimizerConfig
 from ..configs import ARCHS, get_config, get_smoke_config
 from ..data import PrefetchPipeline, SyntheticTokens
 from ..parallel import ShardingRules
-from ..steps import init_train_state, make_train_step
+from ..steps import init_train_state, make_train_step, train_state_shapes
+from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh, make_production_mesh
 
 
+def build_sharded_train(cfg, mesh, opt_cfg: OptimizerConfig, *, seed: int = 0,
+                        microbatches: int = 1):
+    """Train state placed on ``mesh`` by ``ShardingRules`` (ZeRO-1 moments)
+    and the jitted step over it.  The state is initialised straight into its
+    shards, so no device holds it whole.
+
+    Returns (step_fn, params, opt); ``step_fn(params, opt, batch, step)``
+    donates params and opt and returns (params, opt, metrics)."""
+    rules = ShardingRules(cfg, mesh)
+    p_shape, _ = train_state_shapes(cfg)
+    p_sh = jax.tree.map(rules.sharding, rules.param_specs(p_shape))
+    m_sh = jax.tree.map(rules.sharding, rules.opt_specs(p_shape))
+    o_sh = {"m": m_sh, "v": m_sh,
+            "count": rules.sharding(jax.sharding.PartitionSpec())}
+    params, opt = jax.jit(lambda k: init_train_state(cfg, k),
+                          out_shardings=(p_sh, o_sh))(jax.random.key(seed))
+    step_fn = jax.jit(
+        make_train_step(cfg, opt_cfg, rules, microbatches=microbatches),
+        in_shardings=(p_sh, o_sh, None, None),
+        out_shardings=(p_sh, o_sh, None),
+        donate_argnums=(0, 1))
+    return step_fn, params, opt
+
+
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCHS)
     ap.add_argument("--smoke", action="store_true",
@@ -48,36 +74,21 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    if "TPU_WORKER_ID" in os.environ:          # multi-host TPU launch
+    hosts = [h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+             if h]
+    if len(hosts) > 1:                         # multi-host TPU launch
         jax.distributed.initialize()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if cfg.is_encdec or cfg.frontend != "none":
-        # frontends are stubs: the synthetic pipeline provides them
-        pass
-
     mesh = (make_production_mesh() if args.production_mesh
             else make_host_mesh(model=args.model_parallel))
-    rules = ShardingRules(cfg, mesh)
     print(f"arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "
           f"mesh={dict(mesh.shape)} devices={mesh.devices.size}")
 
-    params, opt = init_train_state(cfg, jax.random.key(args.seed))
-    p_sh = jax.tree.map(rules.sharding, rules.param_specs(params))
-    m_sh = jax.tree.map(rules.sharding, rules.opt_specs(params))
-    o_sh = {"m": m_sh, "v": m_sh,
-            "count": rules.sharding(jax.sharding.PartitionSpec())}
-    params = jax.device_put(params, p_sh)
-    opt = jax.device_put(opt, o_sh)
-
     opt_cfg = OptimizerConfig(lr=args.lr, warmup_steps=max(args.steps // 10, 1),
                               total_steps=args.steps)
-    step_fn = jax.jit(
-        make_train_step(cfg, opt_cfg, rules,
-                        microbatches=args.microbatches),
-        in_shardings=(p_sh, o_sh, None, None),
-        out_shardings=(p_sh, o_sh, None),
-        donate_argnums=(0, 1))
+    step_fn, params, opt = build_sharded_train(
+        cfg, mesh, opt_cfg, seed=args.seed, microbatches=args.microbatches)
 
     ckpt_dir = args.ckpt_dir or f"/tmp/repro_{cfg.name}"
     mgr = CheckpointManager(ckpt_dir, keep=2, async_save=True)

@@ -415,13 +415,15 @@ def _embed_inputs(cfg: ModelConfig, params: Params, batch: Dict,
     return sc(x, "residual"), mask
 
 
-def forward_train(cfg: ModelConfig, params: Params, batch: Dict,
-                  sc: Callable = _id_sc, remat: bool = True,
-                  moe_offset=None):
-    """Full-sequence forward; returns (loss, metrics)."""
+def forward_hidden(cfg: ModelConfig, params: Params, batch: Dict,
+                   sc: Callable = _id_sc, remat: bool = True,
+                   moe_offset=None):
+    """Full-sequence pass without caches, the body of ``forward_train``.
+
+    Returns (final-normed hidden states (B, S, D), loss mask or None, aux);
+    ``hidden @ params["lm_head"]`` gives the logits at every position."""
     x, mask = _embed_inputs(cfg, params, batch, sc)
-    S = x.shape[1]
-    positions = jnp.arange(S)
+    positions = jnp.arange(x.shape[1])
 
     cross_src = None
     if cfg.is_encdec:
@@ -430,8 +432,14 @@ def forward_train(cfg: ModelConfig, params: Params, batch: Dict,
     x, _, aux = _stack(cfg, params, x, positions, None, None,
                        decode=False, cross_src=cross_src, sc=sc, remat=remat,
                        moe_offset=moe_offset)
+    return L.rms_norm(x, params["final_norm"], cfg.norm_eps), mask, aux
 
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+
+def forward_train(cfg: ModelConfig, params: Params, batch: Dict,
+                  sc: Callable = _id_sc, remat: bool = True,
+                  moe_offset=None):
+    """Full-sequence forward; returns (loss, metrics)."""
+    x, mask, aux = forward_hidden(cfg, params, batch, sc, remat, moe_offset)
 
     targets = batch["targets"]
     if cfg.frontend == "vision_stub":

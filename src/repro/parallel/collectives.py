@@ -21,23 +21,10 @@ from __future__ import annotations
 import functools
 from typing import Any
 
-import inspect
-
 import jax
 import jax.numpy as jnp
-
-try:                                    # newer jax: top-level export
-    from jax import shard_map
-except ImportError:                     # jax 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma; probe the
-# signature rather than keying off the import location (some versions export
-# jax.shard_map while still taking check_rep)
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(shard_map).parameters
-             else "check_rep")
-
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..optim.compression import dequantize_int8, quantize_int8
 
@@ -78,6 +65,6 @@ def hierarchical_grad_sync(grads: Any, mesh: Mesh,
         mesh=mesh,
         in_specs=P(),            # grads replicated per (pod,data) pair...
         out_specs=P(),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     return fn(grads)

@@ -903,9 +903,9 @@ class JaxServeEngine:
         self.max_len = max_len
         self.admission = make_admission(admission_kind, n_slots,
                                         promote_every=promote_every)
-        self._decode = jax.jit(
+        self.decode_fn = jax.jit(
             lambda p, c, t: decode_step(cfg, p, c, t))
-        self._prefill = jax.jit(
+        self.prefill_fn = jax.jit(
             lambda p, b: prefill(cfg, p, b, max_len=max_len))
         self._jnp = jnp
 
@@ -935,15 +935,14 @@ class JaxServeEngine:
                 break
             # (re)prefill the active batch
             batch = {"tokens": jnp.asarray(prompts[active])}
-            logits, cache = self._prefill(self.params, batch)
+            logits, cache = self.prefill_fn(self.params, batch)
             tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-            steps = gen_len - min(progress[s] for s in active)
             for t in range(gen_len):
                 for j, sid in enumerate(active):
                     if progress[sid] < gen_len:
                         out[sid, progress[sid]] = int(tok[j, 0])
                         progress[sid] += 1
-                logits, cache = self._decode(self.params, cache, tok)
+                logits, cache = self.decode_fn(self.params, cache, tok)
                 tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
             done = [sid for sid in active if progress[sid] >= gen_len]
             for sid in done:

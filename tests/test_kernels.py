@@ -107,3 +107,18 @@ def test_flash_matches_model_xla_path():
     want = xla_flash(q, k, v, jnp.arange(S), jnp.arange(S), 0, True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("op,args", [
+    (flash_attention, lambda: [jnp.zeros((1, 128, 1, 128))] * 3),
+    (ssd, lambda: [jnp.zeros((1, 128, 1, 64)), jnp.zeros((1, 128, 1)),
+                   jnp.zeros((1, 128, 64)), jnp.zeros((1, 128, 64))]),
+    (wkv, lambda: [jnp.zeros((1, 16, 1, 64))] * 4 + [jnp.zeros((1, 64))]),
+    (grouped_matmul, lambda: [jnp.zeros((1, 128, 128))] * 2),
+])
+def test_auto_impl_refuses_non_tpu_backend(op, args):
+    """impl="auto" is Pallas on a TPU; elsewhere it raises rather than
+    quietly running the interpreter."""
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match="TPU only"):
+        op(*args())

@@ -8,9 +8,8 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get_config, get_smoke_config
-from repro.models import decode_step, forward_train, init_params, prefill
-from repro.models import layers as L
-from repro.models.transformer import _embed_inputs, _encode, _stack
+from repro.models import (decode_step, forward_hidden, forward_train,
+                          init_params, prefill)
 
 KEY = jax.random.key(0)
 
@@ -59,15 +58,8 @@ def test_smoke_prefill_decode(arch):
 
 
 def _full_logits(cfg, params, batch):
-    sc = lambda x, kind=None: x  # noqa: E731
-    x, _ = _embed_inputs(cfg, params, batch, sc)
-    positions = jnp.arange(x.shape[1])
-    cross = (_encode(cfg, params, batch["frames"], sc, False)
-             if cfg.is_encdec else None)
-    x, _, _ = _stack(cfg, params, x, positions, None, None, decode=False,
-                     cross_src=cross, sc=sc, remat=False)
-    return L.rms_norm(x, params["final_norm"], cfg.norm_eps) \
-        @ params["lm_head"]
+    hidden, _, _ = forward_hidden(cfg, params, batch, remat=False)
+    return hidden @ params["lm_head"]
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "zamba2-2.7b", "rwkv6-7b",

@@ -6,9 +6,11 @@ XLA_FLAGS before importing jax.
 """
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -91,24 +93,15 @@ _SUBPROCESS_TRAIN = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.configs import get_smoke_config
-    from repro.parallel import ShardingRules
-    from repro.steps import init_train_state, make_train_step
     from repro.config import OptimizerConfig
+    from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import build_sharded_train
 
     cfg = get_smoke_config("qwen3-0.6b")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
-    rules = ShardingRules(cfg, mesh)
-    params, opt = init_train_state(cfg, jax.random.key(0))
-    p_sh = jax.tree.map(rules.sharding, rules.param_specs(params))
-    m_sh = jax.tree.map(rules.sharding, rules.opt_specs(params))
-    o_sh = {"m": m_sh, "v": m_sh,
-            "count": rules.sharding(jax.sharding.PartitionSpec())}
-    params = jax.device_put(params, p_sh)
-    opt = jax.device_put(opt, o_sh)
-    step = make_train_step(cfg, OptimizerConfig(lr=1e-3), rules)
-    jstep = jax.jit(step, in_shardings=(p_sh, o_sh, None, None),
-                    out_shardings=(p_sh, o_sh, None),
-                    donate_argnums=(0, 1))
+    mesh = make_host_mesh(model=2)
+    assert dict(mesh.shape) == {"data": 4, "model": 2}
+    jstep, params, opt = build_sharded_train(cfg, mesh,
+                                             OptimizerConfig(lr=1e-3))
     B, S = 8, 32
     key = jax.random.key(1)
     losses = []
@@ -148,9 +141,8 @@ def _run_sub(code: str) -> dict:
     proc = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+        cwd=Path(__file__).resolve().parents[1])
     assert proc.returncode == 0, proc.stderr[-3000:]
     return json.loads(proc.stdout.strip().splitlines()[-1])
 

@@ -78,7 +78,6 @@ def test_hlo_analyzer_loop_correction():
     w = jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)
     c = jax.jit(f_scan).lower(x, w).compile()
     walker = analyze_hlo(c.as_text())["flops"]
-    # cost_analysis() returns a list on some jaxlib versions, a dict on others
     xla = normalize_cost_analysis(c.cost_analysis()).get("flops", 0.0)
     expected = 8 * 2 * 64 * 128 * 128
     assert walker >= expected                   # loop-corrected
