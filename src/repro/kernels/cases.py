@@ -87,21 +87,13 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
         k = jax.random.split(key, 2)
         return [_normal(k[0], *shapes[0]), _normal(k[1], *shapes[1], 0.05)]
 
+    # each op and oracle at its defaults: causal attention, SSD chunk 128,
+    # WKV chunk 16
     return (
-        KernelCase("flash_attention",
-                   lambda q, k, v, impl: flash_attention(q, k, v, impl=impl),
-                   attention_ref, (qkv,) * 3, flash_draw),
-        KernelCase("mamba2_ssd",
-                   lambda x, a, b, c, impl: ssd(x, a, b, c, chunk=128,
-                                                impl=impl),
-                   lambda x, a, b, c: ssd_ref(x, a, b, c, chunk=128),
-                   ssd_shapes, ssd_draw),
-        KernelCase("rwkv6_wkv",
-                   lambda r, k, v, w, u, impl: wkv(r, k, v, w, u, chunk=16,
-                                                   impl=impl),
-                   lambda r, k, v, w, u: wkv_ref(r, k, v, w, u, chunk=16),
+        KernelCase("flash_attention", flash_attention, attention_ref,
+                   (qkv,) * 3, flash_draw),
+        KernelCase("mamba2_ssd", ssd, ssd_ref, ssd_shapes, ssd_draw),
+        KernelCase("rwkv6_wkv", wkv, wkv_ref,
                    (rkvw,) * 4 + (((Hr, Pr), "float32"),), wkv_draw),
-        KernelCase("moe_gmm",
-                   lambda x, w, impl: grouped_matmul(x, w, impl=impl),
-                   gmm_ref, gmm_shapes, gmm_draw),
+        KernelCase("moe_gmm", grouped_matmul, gmm_ref, gmm_shapes, gmm_draw),
     )
