@@ -94,7 +94,7 @@ def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
     print(f"serve: arch={cfg.name} d_model={cfg.d_model} "
           f"layers={cfg.n_layers} vocab={cfg.vocab_size} streams={streams} "
           f"slots={slots} prompt_len={prompt_len} gen_len={gen_len} "
-          f"parked={getattr(eng.admission, 'stat_parked', 0)}")
+          f"parked={eng.stats.streams_parked}")
     if tokens.shape != (streams, gen_len):
         raise AssertionError(f"tokens shape {tokens.shape}")
     if not ((tokens >= 0) & (tokens < cfg.vocab_size)).all():

@@ -27,7 +27,7 @@ def real_model_demo() -> None:
     print("== real-model engine (8 streams, 3 slots, GCR admission) ==")
     print(f"generated shape: {out.shape}; "
           f"fast admits: {eng.admission.stat_fast}, "
-          f"parked: {eng.admission.stat_parked}")
+          f"parked: {eng.stats.streams_parked}")
     print("first stream tokens:", out[0].tolist())
 
 

@@ -199,7 +199,7 @@ def main() -> None:
     print(f"arch={eng.cfg.name} streams={args.streams} slots={args.slots} "
           f"admission={args.admission}")
     print(f"fast admits: {eng.admission.stat_fast}  "
-          f"parked: {getattr(eng.admission, 'stat_parked', 0)}")
+          f"parked: {eng.stats.streams_parked}")
     for i in range(min(3, args.streams)):
         print(f"stream {i}: {out[i].tolist()}")
 

@@ -881,6 +881,28 @@ def make_admission(kind: str, active_limit: int, n_pods: int = 2,
 # Real-model engine (CPU examples / integration tests)
 # ---------------------------------------------------------------------------
 
+# Host spans of ``JaxServeEngine.generate`` in the profiler's trace
+# (``jax.profiler.TraceAnnotation``; free while no trace is taken).  The
+# wave-level spans carry ``wave``, the per-step ones ``wave`` and ``step``.
+SPAN_GENERATE = "serve.generate"    # one call
+SPAN_ADMIT = "serve.admit"          # the offer loop of one wave
+SPAN_PREFILL = "serve.prefill"      # prompts to the device, prefill, argmax
+SPAN_READOUT = "serve.readout"      # one step's per-lane token reads
+SPAN_DISPATCH = "serve.dispatch"    # one decode step and its argmax
+SPAN_RELEASE = "serve.release"      # the releases that end a wave
+
+
+@dataclass
+class EngineStats:
+    """What ``JaxServeEngine.generate`` has done since the engine was built."""
+
+    calls: int = 0
+    waves: int = 0
+    decode_steps: int = 0
+    tokens_out: int = 0
+    host_reads: int = 0       # device-to-host token reads
+    streams_parked: int = 0   # not admitted in a call's first pass
+
 
 class JaxServeEngine:
     """Batched decode over a real model with fixed slots + GCR admission.
@@ -888,7 +910,8 @@ class JaxServeEngine:
     The batch has ``n_slots`` lanes; admitted streams occupy lanes, parked
     streams wait in the GCR queue.  Prefill is per-stream (lane-local cache
     fill is emulated by re-prefilling the lane batch on admission - adequate
-    for the reduced CPU configs the examples run)."""
+    for the reduced CPU configs the examples run).  ``stats`` counts the
+    work done; ``generate`` marks its phases with the ``SPAN_*`` spans."""
 
     def __init__(self, cfg, params, n_slots: int, max_len: int,
                  admission_kind: str = "gcr", promote_every: int = 16):
@@ -903,49 +926,79 @@ class JaxServeEngine:
         self.max_len = max_len
         self.admission = make_admission(admission_kind, n_slots,
                                         promote_every=promote_every)
-        self.decode_fn = jax.jit(
-            lambda p, c, t: decode_step(cfg, p, c, t))
-        self.prefill_fn = jax.jit(
-            lambda p, b: prefill(cfg, p, b, max_len=max_len))
+        self.stats = EngineStats()
+
+        # named, so the device's trace names the programs after them
+        def serve_decode(p, c, t):
+            return decode_step(cfg, p, c, t)
+
+        def serve_prefill(p, b):
+            return prefill(cfg, p, b, max_len=max_len)
+
+        self.decode_fn = jax.jit(serve_decode)
+        self.prefill_fn = jax.jit(serve_prefill)
         self._jnp = jnp
 
     def generate(self, prompts: "np.ndarray", gen_len: int
                  ) -> "np.ndarray":
         """prompts: (n_streams, prompt_len) int32.  Greedy decode; streams
         beyond the active limit are parked and admitted as slots free."""
+        from jax.profiler import TraceAnnotation as span
+
         jnp = self._jnp
+        stats = self.stats
         n = prompts.shape[0]
         out = np.zeros((n, gen_len), np.int32)
         waiting = list(range(n))
         active: List[int] = []
         progress = {i: 0 for i in range(n)}
+        first_pass = True
+        stats.calls += 1
 
-        while waiting or active:
-            # admission
-            newly = []
-            while waiting:
-                sid = waiting[0]
-                if self.admission.offer(sid):
-                    newly.append(sid)
-                    waiting.pop(0)
-                else:
-                    break  # queue is FIFO; head parked => all parked
-            active.extend(newly)
-            if not active:
-                break
-            # (re)prefill the active batch
-            batch = {"tokens": jnp.asarray(prompts[active])}
-            logits, cache = self.prefill_fn(self.params, batch)
-            tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-            for t in range(gen_len):
-                for j, sid in enumerate(active):
-                    if progress[sid] < gen_len:
-                        out[sid, progress[sid]] = int(tok[j, 0])
-                        progress[sid] += 1
-                logits, cache = self.decode_fn(self.params, cache, tok)
-                tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
-            done = [sid for sid in active if progress[sid] >= gen_len]
-            for sid in done:
-                self.admission.release(sid)
-            active = [sid for sid in active if progress[sid] < gen_len]
+        with span(SPAN_GENERATE):
+            while waiting or active:
+                wave = stats.waves
+                with span(SPAN_ADMIT, wave=wave):
+                    newly = []
+                    while waiting:
+                        sid = waiting[0]
+                        if self.admission.offer(sid):
+                            newly.append(sid)
+                            waiting.pop(0)
+                        else:
+                            break  # queue is FIFO; head parked => all parked
+                    if first_pass:
+                        stats.streams_parked += len(waiting)
+                        first_pass = False
+                active.extend(newly)
+                if not active:
+                    break
+                stats.waves += 1
+                # (re)prefill the active batch
+                with span(SPAN_PREFILL, wave=wave):
+                    batch = {"tokens": jnp.asarray(prompts[active])}
+                    logits, cache = self.prefill_fn(self.params, batch)
+                    tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(
+                        jnp.int32)
+                reads = 0
+                for t in range(gen_len):
+                    with span(SPAN_READOUT, wave=wave, step=t):
+                        for j, sid in enumerate(active):
+                            if progress[sid] < gen_len:
+                                out[sid, progress[sid]] = int(tok[j, 0])
+                                progress[sid] += 1
+                                reads += 1
+                    with span(SPAN_DISPATCH, wave=wave, step=t):
+                        logits, cache = self.decode_fn(self.params, cache,
+                                                       tok)
+                        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(
+                            jnp.int32)
+                stats.decode_steps += gen_len
+                stats.tokens_out += reads
+                stats.host_reads += reads        # one read per token
+                with span(SPAN_RELEASE, wave=wave):
+                    done = [sid for sid in active if progress[sid] >= gen_len]
+                    for sid in done:
+                        self.admission.release(sid)
+                active = [sid for sid in active if progress[sid] < gen_len]
         return out

@@ -114,6 +114,7 @@ def test_serve_cli_runs_the_smoke_config():
                 PYTHONPATH="src")
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "arch=qwen3-0.6b streams=3 slots=2" in proc.stdout
+    assert "parked: 1" in proc.stdout       # the third stream waited a wave
     assert proc.stdout.count("stream ") == 3
 
 

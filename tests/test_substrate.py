@@ -203,18 +203,100 @@ def test_serving_pod_locality():
     assert pod.token_throughput >= 0.95 * gcr.token_throughput
 
 
-def test_jax_serve_engine_generates():
+def _small_engine(n_slots=2):
     from repro.models import init_params
     from repro.serving.engine import JaxServeEngine
 
     cfg = get_smoke_config("qwen3-0.6b")
     params = init_params(cfg, jax.random.key(0))
-    eng = JaxServeEngine(cfg, params, n_slots=2, max_len=24,
-                         admission_kind="gcr")
-    prompts = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (5, 8)).astype(np.int32)
-    out = eng.generate(prompts, gen_len=4)
+    return JaxServeEngine(cfg, params, n_slots=n_slots, max_len=24,
+                          admission_kind="gcr")
+
+
+def _prompts(n, seed=0):
+    vocab = get_smoke_config("qwen3-0.6b").vocab_size
+    return np.random.default_rng(seed).integers(
+        0, vocab, (n, 8)).astype(np.int32)
+
+
+def test_jax_serve_engine_generates():
+    eng = _small_engine(n_slots=2)
+    out = eng.generate(_prompts(5), gen_len=4)
     assert out.shape == (5, 4)
-    assert (out >= 0).all() and (out < cfg.vocab_padded).all()
+    assert (out >= 0).all() and (out < eng.cfg.vocab_padded).all()
     # more streams than slots => the GCR queue was exercised
     assert eng.admission.stat_parked > 0
+
+
+def test_jax_serve_engine_counts_streams_waves_steps_and_reads():
+    eng = _small_engine(n_slots=2)
+    eng.generate(_prompts(5), gen_len=4)
+    # waves of 2, 2 and 1 lanes: every lane reads one token a step
+    s = eng.stats
+    assert (s.calls, s.waves, s.decode_steps) == (1, 3, 12)
+    assert s.host_reads == (2 + 2 + 1) * 4 == s.tokens_out
+    assert s.streams_parked == 5 - 2
+    # the simulator's counter keeps its meaning: offers refused, here the
+    # queue's head in the first two waves
+    assert eng.admission.stat_parked == 2
+    eng.generate(_prompts(4, seed=1), gen_len=3)
+    assert (s.calls, s.waves, s.decode_steps) == (2, 5, 18)
+    assert s.host_reads == 20 + (2 + 2) * 3 == s.tokens_out
+    assert s.streams_parked == 3 + (4 - 2)
+
+
+def test_jax_serve_engine_fits_one_wave_without_parking():
+    eng = _small_engine(n_slots=4)
+    eng.generate(_prompts(3), gen_len=2)
+    s = eng.stats
+    assert (s.waves, s.decode_steps, s.host_reads, s.streams_parked) == (
+        1, 2, 6, 0)
+
+
+def test_jax_serve_engine_programs_have_stable_names():
+    from repro.models import prefill
+
+    eng = _small_engine()
+    batch = {"tokens": jnp.asarray(_prompts(2))}
+    low = eng.prefill_fn.lower(eng.params, batch)
+    assert "module @jit_serve_prefill" in low.as_text()
+    _, cache = jax.eval_shape(
+        lambda p, b: prefill(eng.cfg, p, b, max_len=24), eng.params, batch)
+    tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    low = eng.decode_fn.lower(eng.params, cache, tok)
+    assert "module @jit_serve_decode" in low.as_text()
+
+
+def test_jax_serve_engine_spans_in_the_profilers_trace(tmp_path):
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    from repro.serving import engine as E
+
+    eng = _small_engine(n_slots=2)
+    eng.generate(_prompts(3), gen_len=2)          # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.generate(_prompts(3, seed=1), gen_len=2)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = Path(tmp_path).rglob("*.xplane.pb")
+    spans = [(e.name, dict(e.stats))
+             for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    names = [n for n, _ in spans]
+    assert set(names) == {E.SPAN_GENERATE, E.SPAN_ADMIT, E.SPAN_PREFILL,
+                          E.SPAN_READOUT, E.SPAN_DISPATCH, E.SPAN_RELEASE}
+    # two waves (2 lanes, then 1) of 2 steps
+    assert names.count(E.SPAN_GENERATE) == 1
+    assert names.count(E.SPAN_PREFILL) == names.count(E.SPAN_RELEASE) == 2
+    assert names.count(E.SPAN_READOUT) == names.count(E.SPAN_DISPATCH) == 4
+    # metadata rides as stats, the name stays exact
+    waves = {st["wave"] for n, st in spans if n == E.SPAN_PREFILL}
+    assert waves == {2, 3}                        # the engine's 3rd and 4th
+    assert sorted((st["wave"], st["step"]) for n, st in spans
+                  if n == E.SPAN_READOUT) == [(2, 0), (2, 1), (3, 0), (3, 1)]
