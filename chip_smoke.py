@@ -10,7 +10,8 @@ are served in a second wave, with 1024-token prompts and 32 generated
 tokens.  It checks that every token is in the
 vocabulary, that a second ``generate`` gives the same tokens, and that the
 last-position logits of ``prefill`` match the cache-free pass of
-``forward_train``.  Then it runs each Pallas kernel natively at the widths of
+``forward_train`` and that the engine's prefill program serves their
+argmax.  Then it runs each Pallas kernel natively at the widths of
 ``repro.kernels.cases`` against its ``ref.py`` oracle.
 
 ``--four-chips``: three steps of qwen3-0.6b training at full width, built as
@@ -84,7 +85,7 @@ def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
     import numpy as np
 
     from repro.launch.serve import serve_real_model
-    from repro.models import forward_hidden
+    from repro.models import forward_hidden, prefill
 
     with clock.phase("serve_first_generate"):
         eng, prompts, tokens = serve_real_model(
@@ -108,14 +109,22 @@ def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
 
     with clock.phase("prefill_vs_forward"):
         batch = {"tokens": jax.numpy.asarray(prompts[:slots])}
-        logits, _ = eng.prefill_fn(eng.params, batch)
+        logits, _ = jax.jit(lambda p, b: prefill(cfg, p, b,
+                                                 max_len=eng.max_len))(
+            eng.params, batch)
         ref = jax.jit(lambda p, b: forward_hidden(cfg, p, b, remat=False)[0]
                       [:, -1:] @ p["lm_head"])(eng.params, batch)
         err = _rel_err(logits, ref)
+        # the engine's prefill program serves the argmax of those logits
+        tok, _ = eng.prefill_fn(eng.params, batch)
+        greedy = np.asarray(logits)[:, -1].argmax(-1)[:, None]
     print(f"serve: prefill vs forward max_rel_err={err:.3e} "
           f"(tol {LOGIT_TOL:g}) first tokens={tokens[0, :8].tolist()}")
     if not err <= LOGIT_TOL:
         raise AssertionError(f"prefill logits off by {err:.3e}")
+    if not np.array_equal(np.asarray(tok), greedy):
+        raise AssertionError(f"prefill_fn's tokens {np.asarray(tok)[:, 0]} "
+                             f"are not the logits' argmax {greedy[:, 0]}")
 
 
 def check_kernels(clock, cases, impl="pallas"):

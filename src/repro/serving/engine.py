@@ -883,12 +883,13 @@ def make_admission(kind: str, active_limit: int, n_pods: int = 2,
 
 # Host spans of ``JaxServeEngine.generate`` in the profiler's trace
 # (``jax.profiler.TraceAnnotation``; free while no trace is taken).  The
-# wave-level spans carry ``wave``, the per-step ones ``wave`` and ``step``.
+# spans carry ``wave``; ``serve.dispatch`` and ``serve.readout`` also carry
+# ``step`` (the readout's is the wave's last).
 SPAN_GENERATE = "serve.generate"    # one call
 SPAN_ADMIT = "serve.admit"          # the offer loop of one wave
-SPAN_PREFILL = "serve.prefill"      # prompts to the device, prefill, argmax
-SPAN_READOUT = "serve.readout"      # one step's per-lane token reads
-SPAN_DISPATCH = "serve.dispatch"    # one decode step and its argmax
+SPAN_PREFILL = "serve.prefill"      # prompts to the device, prefill
+SPAN_READOUT = "serve.readout"      # a wave's tokens to the host, once
+SPAN_DISPATCH = "serve.dispatch"    # one decode step
 SPAN_RELEASE = "serve.release"      # the releases that end a wave
 
 
@@ -900,7 +901,7 @@ class EngineStats:
     waves: int = 0
     decode_steps: int = 0
     tokens_out: int = 0
-    host_reads: int = 0       # device-to-host token reads
+    host_reads: int = 0       # tokens brought to the host, one per token
     streams_parked: int = 0   # not admitted in a call's first pass
 
 
@@ -910,8 +911,10 @@ class JaxServeEngine:
     The batch has ``n_slots`` lanes; admitted streams occupy lanes, parked
     streams wait in the GCR queue.  Prefill is per-stream (lane-local cache
     fill is emulated by re-prefilling the lane batch on admission - adequate
-    for the reduced CPU configs the examples run).  ``stats`` counts the
-    work done; ``generate`` marks its phases with the ``SPAN_*`` spans."""
+    for the reduced CPU configs the examples run).  Both step programs take
+    the greedy token on the device, so a wave's tokens stay there until it
+    ends.  ``stats`` counts the work done; ``generate`` marks its phases
+    with the ``SPAN_*`` spans."""
 
     def __init__(self, cfg, params, n_slots: int, max_len: int,
                  admission_kind: str = "gcr", promote_every: int = 16):
@@ -928,77 +931,83 @@ class JaxServeEngine:
                                         promote_every=promote_every)
         self.stats = EngineStats()
 
+        def greedy(logits):
+            return jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+
         # named, so the device's trace names the programs after them
         def serve_decode(p, c, t):
-            return decode_step(cfg, p, c, t)
+            logits, c = decode_step(cfg, p, c, t)
+            return greedy(logits), c
 
         def serve_prefill(p, b):
-            return prefill(cfg, p, b, max_len=max_len)
+            logits, c = prefill(cfg, p, b, max_len=max_len)
+            return greedy(logits), c
 
         self.decode_fn = jax.jit(serve_decode)
         self.prefill_fn = jax.jit(serve_prefill)
-        self._jnp = jnp
 
     def generate(self, prompts: "np.ndarray", gen_len: int
                  ) -> "np.ndarray":
         """prompts: (n_streams, prompt_len) int32.  Greedy decode; streams
-        beyond the active limit are parked and admitted as slots free."""
+        beyond the active limit are parked and admitted as slots free.
+
+        Every admitted stream runs ``gen_len`` steps in its wave.  Each step
+        is fed the previous step's token on the device, the host keeps up to
+        two steps queued without reading any, and it reads the served tokens
+        back once, after the wave's last step is done."""
+        import jax
+        import jax.numpy as jnp
         from jax.profiler import TraceAnnotation as span
 
-        jnp = self._jnp
         stats = self.stats
         n = prompts.shape[0]
         out = np.zeros((n, gen_len), np.int32)
         waiting = list(range(n))
-        active: List[int] = []
-        progress = {i: 0 for i in range(n)}
         first_pass = True
         stats.calls += 1
 
         with span(SPAN_GENERATE):
-            while waiting or active:
+            while waiting:
                 wave = stats.waves
                 with span(SPAN_ADMIT, wave=wave):
-                    newly = []
+                    active = []
                     while waiting:
                         sid = waiting[0]
                         if self.admission.offer(sid):
-                            newly.append(sid)
+                            active.append(sid)
                             waiting.pop(0)
                         else:
                             break  # queue is FIFO; head parked => all parked
                     if first_pass:
                         stats.streams_parked += len(waiting)
                         first_pass = False
-                active.extend(newly)
                 if not active:
                     break
                 stats.waves += 1
                 # (re)prefill the active batch
                 with span(SPAN_PREFILL, wave=wave):
                     batch = {"tokens": jnp.asarray(prompts[active])}
-                    logits, cache = self.prefill_fn(self.params, batch)
-                    tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(
-                        jnp.int32)
-                reads = 0
+                    tok, cache = self.prefill_fn(self.params, batch)
+                toks = [tok]
                 for t in range(gen_len):
-                    with span(SPAN_READOUT, wave=wave, step=t):
-                        for j, sid in enumerate(active):
-                            if progress[sid] < gen_len:
-                                out[sid, progress[sid]] = int(tok[j, 0])
-                                progress[sid] += 1
-                                reads += 1
+                    if t >= 2:
+                        # step t-2 done: at most two steps in flight, as
+                        # each holds a fresh copy of the cache
+                        toks[t - 1].block_until_ready()
                     with span(SPAN_DISPATCH, wave=wave, step=t):
-                        logits, cache = self.decode_fn(self.params, cache,
-                                                       tok)
-                        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(
-                            jnp.int32)
+                        tok, cache = self.decode_fn(self.params, cache, tok)
+                    toks.append(tok)
+                # wait on the last step dispatched (its token is not
+                # served) before reading the served ones back
+                with span(SPAN_READOUT, wave=wave, step=gen_len - 1):
+                    toks[-1].block_until_ready()
+                    served = jax.device_get(toks[:gen_len])
+                for t, col in enumerate(served):
+                    out[active, t] = col[:, 0]
                 stats.decode_steps += gen_len
-                stats.tokens_out += reads
-                stats.host_reads += reads        # one read per token
+                stats.tokens_out += len(active) * gen_len
+                stats.host_reads += len(active) * gen_len
                 with span(SPAN_RELEASE, wave=wave):
-                    done = [sid for sid in active if progress[sid] >= gen_len]
-                    for sid in done:
+                    for sid in active:
                         self.admission.release(sid)
-                active = [sid for sid in active if progress[sid] < gen_len]
         return out

@@ -231,7 +231,7 @@ def test_jax_serve_engine_generates():
 def test_jax_serve_engine_counts_streams_waves_steps_and_reads():
     eng = _small_engine(n_slots=2)
     eng.generate(_prompts(5), gen_len=4)
-    # waves of 2, 2 and 1 lanes: every lane reads one token a step
+    # waves of 2, 2 and 1 lanes: one token per lane and step to the host
     s = eng.stats
     assert (s.calls, s.waves, s.decode_steps) == (1, 3, 12)
     assert s.host_reads == (2 + 2 + 1) * 4 == s.tokens_out
@@ -253,6 +253,36 @@ def test_jax_serve_engine_fits_one_wave_without_parking():
         1, 2, 6, 0)
 
 
+def _host_loop_reference(eng, prompts, gen_len, waves):
+    """The tokens of greedy decode with the argmax on the host: the model's
+    own ``prefill``/``decode_step``, jitted, on each wave's lanes, and each
+    lane fed its token one at a time."""
+    from repro.models import decode_step, prefill
+
+    pre = jax.jit(lambda p, b: prefill(eng.cfg, p, b, max_len=eng.max_len))
+    dec = jax.jit(lambda p, c, t: decode_step(eng.cfg, p, c, t))
+    out = np.zeros((len(prompts), gen_len), np.int32)
+    for lanes in waves:
+        logits, cache = pre(eng.params,
+                            {"tokens": jnp.asarray(prompts[lanes])})
+        for t in range(gen_len):
+            best = np.asarray(logits)[:, -1].argmax(-1)
+            tok = np.zeros((len(lanes), 1), np.int32)
+            for j, sid in enumerate(lanes):
+                out[sid, t] = tok[j, 0] = best[j]
+            logits, cache = dec(eng.params, cache, jnp.asarray(tok))
+    return out
+
+
+def test_jax_serve_engine_serves_the_host_loops_tokens():
+    eng = _small_engine(n_slots=2)
+    prompts = _prompts(5, seed=3)
+    out = eng.generate(prompts, gen_len=4)
+    assert eng.stats.waves == 3
+    want = _host_loop_reference(eng, prompts, 4, [[0, 1], [2, 3], [4]])
+    np.testing.assert_array_equal(out, want)
+
+
 def test_jax_serve_engine_programs_have_stable_names():
     from repro.models import prefill
 
@@ -265,38 +295,78 @@ def test_jax_serve_engine_programs_have_stable_names():
     tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
     low = eng.decode_fn.lower(eng.params, cache, tok)
     assert "module @jit_serve_decode" in low.as_text()
+    # both step programs hand back the greedy token, not the logits
+    assert jax.eval_shape(eng.prefill_fn, eng.params, batch)[0] == tok
+    assert jax.eval_shape(eng.decode_fn, eng.params, cache, tok)[0] == tok
 
 
-def test_jax_serve_engine_spans_in_the_profilers_trace(tmp_path):
+def _traced_generate(tmp_path, eng, prompts, gen_len):
+    """Host events ``(name, start_ns, end_ns, stats)`` of the thread that ran
+    one ``generate`` under the profiler, in time order."""
     from pathlib import Path
 
     from jax.profiler import ProfileData
 
-    from repro.serving import engine as E
-
-    eng = _small_engine(n_slots=2)
-    eng.generate(_prompts(3), gen_len=2)          # compile outside the trace
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        eng.generate(_prompts(3, seed=1), gen_len=2)
+        eng.generate(prompts, gen_len=gen_len)
     finally:
         jax.profiler.stop_trace()
     (path,) = Path(tmp_path).rglob("*.xplane.pb")
-    spans = [(e.name, dict(e.stats))
-             for plane in ProfileData.from_file(str(path)).planes
-             for line in plane.lines for e in line.events
-             if e.name.startswith("serve.")]
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            evs = [(e.name, int(e.start_ns),
+                    int(e.start_ns) + int(e.duration_ns), dict(e.stats))
+                   for e in line.events]
+            if any(n.startswith("serve.") for n, *_ in evs):
+                return sorted(evs, key=lambda e: e[1])
+    raise AssertionError("no serve.* spans in the trace")
+
+
+def test_jax_serve_engine_spans_in_the_profilers_trace(tmp_path):
+    from repro.serving import engine as E
+
+    eng = _small_engine(n_slots=2)
+    eng.generate(_prompts(3), gen_len=2)          # compile outside the trace
+    spans = [(n, st) for n, _, _, st in _traced_generate(
+        tmp_path, eng, _prompts(3, seed=1), 2) if n.startswith("serve.")]
     names = [n for n, _ in spans]
     assert set(names) == {E.SPAN_GENERATE, E.SPAN_ADMIT, E.SPAN_PREFILL,
                           E.SPAN_READOUT, E.SPAN_DISPATCH, E.SPAN_RELEASE}
-    # two waves (2 lanes, then 1) of 2 steps
+    # two waves (2 lanes, then 1) of 2 steps, read back once a wave
     assert names.count(E.SPAN_GENERATE) == 1
     assert names.count(E.SPAN_PREFILL) == names.count(E.SPAN_RELEASE) == 2
-    assert names.count(E.SPAN_READOUT) == names.count(E.SPAN_DISPATCH) == 4
+    assert names.count(E.SPAN_READOUT) == 2
+    assert names.count(E.SPAN_DISPATCH) == 4
     # metadata rides as stats, the name stays exact
     waves = {st["wave"] for n, st in spans if n == E.SPAN_PREFILL}
     assert waves == {2, 3}                        # the engine's 3rd and 4th
     assert sorted((st["wave"], st["step"]) for n, st in spans
-                  if n == E.SPAN_READOUT) == [(2, 0), (2, 1), (3, 0), (3, 1)]
+                  if n == E.SPAN_DISPATCH) == [(2, 0), (2, 1), (3, 0), (3, 1)]
+    # the readout carries the wave's last step
+    assert sorted((st["wave"], st["step"]) for n, st in spans
+                  if n == E.SPAN_READOUT) == [(2, 1), (3, 1)]
+
+
+def test_jax_serve_engine_reads_nothing_between_a_waves_dispatches(
+        tmp_path):
+    from repro.serving import engine as E
+
+    read = "np.asarray(jax.Array)"                # JAX's span of a host copy
+    eng = _small_engine(n_slots=2)
+    eng.generate(_prompts(3), gen_len=4)          # compile outside the trace
+    events = _traced_generate(tmp_path, eng, _prompts(3, seed=2), 4)
+    reads = [s for n, s, _, _ in events if n == read]
+    for wave in (2, 3):
+        dispatch = [(s, e) for n, s, e, st in events
+                    if n == E.SPAN_DISPATCH and st["wave"] == wave]
+        assert len(dispatch) == 4
+        first, last = dispatch[0][0], dispatch[-1][1]
+        assert not [s for s in reads if first <= s <= last]
+        # the wave's reads come after its last dispatch, in its readout
+        (readout,) = [(s, e) for n, s, e, st in events
+                      if n == E.SPAN_READOUT and st["wave"] == wave]
+        assert last <= readout[0]
+        assert any(readout[0] <= s <= readout[1] for s in reads)
