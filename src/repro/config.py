@@ -57,6 +57,10 @@ class ModelConfig:
     # token admission with rotating priority for long-term fairness.
     gcr_moe: bool = False
     gcr_moe_rotate_every: int = 64  # steps between priority rotations
+    # dropless routing: every (token, expert) row is computed, sorted by
+    # expert and run as one grouped matmul over ragged groups (no capacity,
+    # so no GCR admission either)
+    moe_dropless: bool = False
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -80,6 +84,16 @@ class ModelConfig:
 
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
+
+    # Granite's scalars (None: absent from the layer equations): the
+    # embedding is multiplied by ``embedding_multiplier``, attention scores
+    # are scaled by ``attention_multiplier`` in place of 1/sqrt(d_head), the
+    # attention and MLP outputs by ``residual_multiplier`` before each
+    # residual add, and the logits are divided by ``logits_scaling``
+    embedding_multiplier: Optional[float] = None
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
 
     # ---- derived ---------------------------------------------------------
     @property

@@ -2,7 +2,8 @@
 
 The TPU compile tests (tests/test_tpu_compile.py) compile these shapes for
 a described v5e, and ``chip_smoke.py`` runs them on the chip against the
-``ref.py`` oracles.
+``ref.py`` oracles.  ``moe_gmm`` (fixed capacity) is off the model's path;
+``moe_ragged_gmm`` is the dropless MoE's grouped matmul.
 """
 
 from __future__ import annotations
@@ -18,8 +19,8 @@ from .flash_attention.ops import flash_attention
 from .flash_attention.ref import attention_ref
 from .mamba2_ssd.ops import ssd
 from .mamba2_ssd.ref import ssd_ref
-from .moe_gmm.ops import grouped_matmul
-from .moe_gmm.ref import gmm_ref
+from .moe_gmm.ops import grouped_matmul, ragged_matmul
+from .moe_gmm.ref import gmm_ref, ragged_ref
 from .rwkv6_wkv.ops import wkv
 from .rwkv6_wkv.ref import wkv_ref
 
@@ -49,6 +50,7 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
     zamba = get_config("zamba2-2.7b")
     rwkv = get_config("rwkv6-7b")
     mixtral = get_config("mixtral-8x7b")
+    granite = get_config("granite-moe-1b-a400m")
 
     # flash attention: qwen3-0.6b heads at a 4k context
     qkv = ((1, 4096, qwen.n_heads, qwen.head_dim), "bfloat16")
@@ -87,6 +89,21 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
         k = jax.random.split(key, 2)
         return [_normal(k[0], *shapes[0]), _normal(k[1], *shapes[1], 0.05)]
 
+    # ragged grouped matmul: granite-moe-1b-a400m's gate projection over a
+    # 1024-token prompt's rows (k per token), routed to the first three
+    # quarters of the experts (the rest empty)
+    Eg, Dg, Fg = granite.n_experts, granite.d_model, granite.moe_d_ff
+    ragged_shapes = (((1024 * granite.n_experts_active, Dg), "bfloat16"),
+                     ((Eg, Dg, Fg), "bfloat16"), ((Eg,), "int32"))
+
+    def ragged_draw(key, shapes):
+        k = jax.random.split(key, 3)
+        (M, _), _, (E,) = (s for s, _ in shapes)
+        experts = jax.random.randint(k[2], (M,), 0, max(1, 3 * E // 4))
+        sizes = jnp.bincount(experts, length=E).astype(jnp.int32)
+        return [_normal(k[0], *shapes[0]), _normal(k[1], *shapes[1], 0.05),
+                sizes]
+
     # each op and oracle at its defaults: causal attention, SSD chunk 128,
     # WKV chunk 16
     return (
@@ -96,4 +113,6 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
         KernelCase("rwkv6_wkv", wkv, wkv_ref,
                    (rkvw,) * 4 + (((Hr, Pr), "float32"),), wkv_draw),
         KernelCase("moe_gmm", grouped_matmul, gmm_ref, gmm_shapes, gmm_draw),
+        KernelCase("moe_ragged_gmm", ragged_matmul, ragged_ref,
+                   ragged_shapes, ragged_draw),
     )

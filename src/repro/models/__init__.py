@@ -1,8 +1,8 @@
 """Pure-JAX model zoo covering the ten assigned architectures."""
 
 from .transformer import (decode_step, forward_hidden, forward_train,
-                          init_cache, init_params, param_shapes, cache_shapes,
-                          prefill)
+                          init_cache, init_params, lm_logits, param_shapes,
+                          cache_shapes, prefill)
 
 __all__ = [
     "cache_shapes",
@@ -11,6 +11,7 @@ __all__ = [
     "forward_train",
     "init_cache",
     "init_params",
+    "lm_logits",
     "param_shapes",
     "prefill",
 ]

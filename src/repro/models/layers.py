@@ -364,9 +364,13 @@ def multihead_attention(
     decode: bool = False,           # True: attend over the cache (S small)
     is_cross: bool = False,         # cross-attention (kv from encoder/cache)
     eps: float = 1e-5,
+    q_scale: Optional[float] = None,  # q times this after RoPE (None: 1)
     sc=lambda x, kind=None: x,      # sharding-constraint hook
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Returns (output (B,S,d_model), updated cache).
+
+    Every path scales scores by 1/sqrt(d_head); a ``q_scale`` of
+    ``m * sqrt(d_head)`` makes that a score scale of ``m``.
 
     Modes:
       train   - cache is None: attend within the sequence.
@@ -408,6 +412,8 @@ def multihead_attention(
             src_pos = positions if not decode else (
                 cache_pos + jnp.arange(Tsrc))
             k = apply_rope(k, src_pos, rope_theta)
+        if q_scale is not None:
+            q = q * jnp.asarray(q_scale, q.dtype)
 
         if cache is not None:
             new_cache = _cache_write(cache, k, v, cache_pos)

@@ -25,6 +25,7 @@ stream / logits / caches to the mesh at block boundaries.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -202,18 +203,29 @@ def cache_shapes(cfg: ModelConfig, B: int, max_len: int, enc_len: int = 0):
 # ---------------------------------------------------------------------------
 
 
+def _scaled(x, m: Optional[float]):
+    """x times a configuration scalar, in float32, back in x's dtype; x
+    itself where the scalar is absent (None), so no multiply is traced."""
+    if m is None:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
 def _apply_attn_block(cfg, p, x, positions, cache, cache_pos, *, decode,
                       causal, cross_src, cross_cache, sc, moe_offset=None):
     """attn (+cross) (+mlp/moe) block. Returns (x, new_cache, new_cross, aux)."""
     aux: Dict[str, jnp.ndarray] = {}
+    res = cfg.residual_multiplier
+    q_scale = (None if cfg.attention_multiplier is None else
+               cfg.attention_multiplier * math.sqrt(cfg.head_dim))
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     attn_out, new_cache = L.multihead_attention(
         p["attn"], h, positions, None, cache, cache_pos,
         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, d_head=cfg.head_dim,
         qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
         window=cfg.sliding_window, causal=causal, decode=decode,
-        eps=cfg.norm_eps, sc=sc)
-    x = sc(x + attn_out, "residual")
+        eps=cfg.norm_eps, q_scale=q_scale, sc=sc)
+    x = sc(x + _scaled(attn_out, res), "residual")
 
     new_cross = cross_cache
     if "cross" in p:
@@ -223,7 +235,7 @@ def _apply_attn_block(cfg, p, x, positions, cache, cache_pos, *, decode,
             n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, d_head=cfg.head_dim,
             causal=False, decode=decode, is_cross=True, eps=cfg.norm_eps,
             sc=sc)
-        x = sc(x + cross_out, "residual")
+        x = sc(x + _scaled(cross_out, res), "residual")
 
     h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if "moe" in p:
@@ -232,10 +244,10 @@ def _apply_attn_block(cfg, p, x, positions, cache, cache_pos, *, decode,
             top_k=cfg.n_experts_active,
             capacity_factor=cfg.moe_capacity_factor,
             gcr_admission=cfg.gcr_moe,
-            priority_offset=moe_offset, sc=sc)
-        x = sc(x + moe_out, "residual")
+            priority_offset=moe_offset, dropless=cfg.moe_dropless, sc=sc)
+        x = sc(x + _scaled(moe_out, res), "residual")
     else:
-        x = sc(x + L.mlp(p["mlp"], h2), "residual")
+        x = sc(x + _scaled(L.mlp(p["mlp"], h2), res), "residual")
     return x, new_cache, new_cross, aux
 
 
@@ -367,8 +379,10 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
             new_caches["cross"] = new_cross
         if cfg.shared_attn_every:
             new_caches["shared"] = shared_out
-    # aux scanned outputs: mean over layers
-    aux = {k: jnp.mean(v) for k, v in aux.items()} if aux else {}
+    # aux scanned outputs: MoE rows summed and the largest group maxed over
+    # layers, the rest averaged
+    reduce = {"moe_rows": jnp.sum, "moe_max_group": jnp.max}
+    aux = {k: reduce.get(k, jnp.mean)(v) for k, v in aux.items()}
     return x, new_caches, aux
 
 
@@ -403,7 +417,7 @@ def _embed_inputs(cfg: ModelConfig, params: Params, batch: Dict,
                   sc) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Returns (decoder input embeddings, loss mask or None)."""
     tok = batch["tokens"]
-    x = params["embed"][tok]
+    x = _scaled(params["embed"][tok], cfg.embedding_multiplier)
     mask = None
     if cfg.frontend == "vision_stub":
         patches = batch["patches"] @ params["frontend_proj"]  # (B,P,D)
@@ -421,7 +435,7 @@ def forward_hidden(cfg: ModelConfig, params: Params, batch: Dict,
     """Full-sequence pass without caches, the body of ``forward_train``.
 
     Returns (final-normed hidden states (B, S, D), loss mask or None, aux);
-    ``hidden @ params["lm_head"]`` gives the logits at every position."""
+    ``lm_logits(cfg, params, hidden)`` gives the logits at every position."""
     x, mask = _embed_inputs(cfg, params, batch, sc)
     positions = jnp.arange(x.shape[1])
 
@@ -433,6 +447,17 @@ def forward_hidden(cfg: ModelConfig, params: Params, batch: Dict,
                        decode=False, cross_src=cross_src, sc=sc, remat=remat,
                        moe_offset=moe_offset)
     return L.rms_norm(x, params["final_norm"], cfg.norm_eps), mask, aux
+
+
+def _logit_scale(cfg: ModelConfig) -> Optional[float]:
+    return None if cfg.logits_scaling is None else 1.0 / cfg.logits_scaling
+
+
+def lm_logits(cfg: ModelConfig, params: Params, hidden, sc: Callable = _id_sc):
+    """Logits of final-normed hidden states (divided by ``logits_scaling``
+    where the configuration has it)."""
+    return _scaled(sc(hidden @ params["lm_head"], "logits"),
+                   _logit_scale(cfg))
 
 
 def forward_train(cfg: ModelConfig, params: Params, batch: Dict,
@@ -447,6 +472,8 @@ def forward_train(cfg: ModelConfig, params: Params, batch: Dict,
         B, P = x.shape[0], x.shape[1] - targets.shape[1]
         targets = jnp.concatenate(
             [jnp.zeros((B, P), targets.dtype), targets], axis=1)
+    # logits / s == (hidden / s) @ lm_head: the chunked loss never holds them
+    x = _scaled(x, _logit_scale(cfg))
     loss = L.chunked_softmax_xent(x, params["lm_head"], targets, mask, sc)
     for k, v in aux.items():
         if k.endswith("_loss"):
@@ -456,8 +483,9 @@ def forward_train(cfg: ModelConfig, params: Params, batch: Dict,
 
 
 def prefill(cfg: ModelConfig, params: Params, batch: Dict, max_len: int,
-            sc: Callable = _id_sc):
-    """Process the prompt; returns (last-token logits, populated cache)."""
+            sc: Callable = _id_sc, return_aux: bool = False):
+    """Process the prompt; returns (last-token logits, populated cache), and
+    with ``return_aux`` the stack's aux metrics third."""
     x, _ = _embed_inputs(cfg, params, batch, sc)
     B, S = x.shape[0], x.shape[1]
     positions = jnp.arange(S)
@@ -469,28 +497,31 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict, max_len: int,
         enc_len = cross_src.shape[1]
 
     caches = init_cache(cfg, B, max_len, enc_len)
-    x, caches, _ = _stack(cfg, params, x, positions, caches, 0,
-                          decode=False, cross_src=cross_src, sc=sc,
-                          remat=False)
+    x, caches, aux = _stack(cfg, params, x, positions, caches, 0,
+                            decode=False, cross_src=cross_src, sc=sc,
+                            remat=False)
     caches["pos"] = jnp.asarray(S, jnp.int32)
 
     x = L.rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
-    logits = sc(x @ params["lm_head"], "logits")
-    return logits, caches
+    logits = lm_logits(cfg, params, x, sc)
+    return (logits, caches, aux) if return_aux else (logits, caches)
 
 
 def decode_step(cfg: ModelConfig, params: Params, caches: Dict,
-                tokens: jnp.ndarray, sc: Callable = _id_sc):
-    """One serving step: tokens (B, 1) -> (logits (B,1,V), updated caches)."""
-    x = sc(params["embed"][tokens], "residual")
+                tokens: jnp.ndarray, sc: Callable = _id_sc,
+                return_aux: bool = False):
+    """One serving step: tokens (B, 1) -> (logits (B,1,V), updated caches),
+    and with ``return_aux`` the stack's aux metrics third."""
+    x = sc(_scaled(params["embed"][tokens], cfg.embedding_multiplier),
+           "residual")
     pos = caches["pos"]
     positions = pos + jnp.arange(tokens.shape[1])
 
-    x, new_caches, _ = _stack(cfg, params, x, positions, caches, pos,
-                              decode=True, cross_src=None, sc=sc,
-                              remat=False)
+    x, new_caches, aux = _stack(cfg, params, x, positions, caches, pos,
+                                decode=True, cross_src=None, sc=sc,
+                                remat=False)
     new_caches["pos"] = pos + tokens.shape[1]
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = sc(x @ params["lm_head"], "logits")
-    return logits, new_caches
+    logits = lm_logits(cfg, params, x, sc)
+    return (logits, new_caches, aux) if return_aux else (logits, new_caches)

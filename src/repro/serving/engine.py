@@ -905,6 +905,32 @@ class EngineStats:
     streams_parked: int = 0   # not admitted in a call's first pass
 
 
+@dataclass
+class MoEStats:
+    """What the MoE layers of ``JaxServeEngine.generate``'s steps computed:
+    (token, expert) rows summed over layers and steps (B*S*k*layers a step
+    when nothing is dropped), and the most rows one expert of one layer took
+    in one step."""
+
+    rows: int = 0
+    max_group: int = 0
+
+
+def _free_cache(done, keep=None) -> None:
+    """Free the buffers of ``done``, a cache no step will read again, now,
+    before the next step allocates its own: left to the garbage collector,
+    the runtime frees them once it notes the last reader's end, on a TPU
+    sometimes only after the next allocation, so the device's peak held a
+    cache more in some runs and not in others.  Buffers that ``keep`` (the
+    newest cache) shares, such as a part a step returns unchanged, stay."""
+    import jax
+
+    kept = {id(a) for a in jax.tree.leaves(keep)}
+    for a in jax.tree.leaves(done):
+        if id(a) not in kept and not a.is_deleted():
+            a.delete()
+
+
 class JaxServeEngine:
     """Batched decode over a real model with fixed slots + GCR admission.
 
@@ -913,8 +939,9 @@ class JaxServeEngine:
     fill is emulated by re-prefilling the lane batch on admission - adequate
     for the reduced CPU configs the examples run).  Both step programs take
     the greedy token on the device, so a wave's tokens stay there until it
-    ends.  ``stats`` counts the work done; ``generate`` marks its phases
-    with the ``SPAN_*`` spans."""
+    ends.  ``stats`` counts the work done, ``moe_stats`` the rows a model
+    with MoE layers computed; ``generate`` marks its phases with the
+    ``SPAN_*`` spans."""
 
     def __init__(self, cfg, params, n_slots: int, max_len: int,
                  admission_kind: str = "gcr", promote_every: int = 16):
@@ -930,18 +957,26 @@ class JaxServeEngine:
         self.admission = make_admission(admission_kind, n_slots,
                                         promote_every=promote_every)
         self.stats = EngineStats()
+        self.moe_stats = MoEStats()
+        moe = "moe" in cfg.layer_kinds()
 
         def greedy(logits):
             return jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
 
+        def outputs(logits, c, aux=None):
+            """(token, cache), and for MoE layers int32[rows, max_group]."""
+            if aux is None:
+                return greedy(logits), c
+            return greedy(logits), c, jnp.stack(
+                [aux["moe_rows"], aux["moe_max_group"]]).astype(jnp.int32)
+
         # named, so the device's trace names the programs after them
         def serve_decode(p, c, t):
-            logits, c = decode_step(cfg, p, c, t)
-            return greedy(logits), c
+            return outputs(*decode_step(cfg, p, c, t, return_aux=moe))
 
         def serve_prefill(p, b):
-            logits, c = prefill(cfg, p, b, max_len=max_len)
-            return greedy(logits), c
+            return outputs(*prefill(cfg, p, b, max_len=max_len,
+                                    return_aux=moe))
 
         self.decode_fn = jax.jit(serve_decode)
         self.prefill_fn = jax.jit(serve_prefill)
@@ -954,7 +989,9 @@ class JaxServeEngine:
         Every admitted stream runs ``gen_len`` steps in its wave.  Each step
         is fed the previous step's token on the device, the host keeps up to
         two steps queued without reading any, and it reads the served tokens
-        back once, after the wave's last step is done."""
+        back once, after the wave's last step is done, with the MoE
+        counts of the wave's steps.  It frees each cache itself once no
+        step will read it again."""
         import jax
         import jax.numpy as jnp
         from jax.profiler import TraceAnnotation as span
@@ -987,23 +1024,36 @@ class JaxServeEngine:
                 # (re)prefill the active batch
                 with span(SPAN_PREFILL, wave=wave):
                     batch = {"tokens": jnp.asarray(prompts[active])}
-                    tok, cache = self.prefill_fn(self.params, batch)
+                    tok, cache, *counts = self.prefill_fn(self.params, batch)
                 toks = [tok]
+                inputs = [cache]        # caches a step may still read
                 for t in range(gen_len):
                     if t >= 2:
                         # step t-2 done: at most two steps in flight, as
-                        # each holds a fresh copy of the cache
+                        # each holds a fresh copy of the cache; its input
+                        # freed before step t allocates
                         toks[t - 1].block_until_ready()
+                        _free_cache(inputs.pop(0), cache)
                     with span(SPAN_DISPATCH, wave=wave, step=t):
-                        tok, cache = self.decode_fn(self.params, cache, tok)
+                        tok, cache, *c = self.decode_fn(self.params, cache,
+                                                        tok)
+                    inputs.append(cache)
                     toks.append(tok)
+                    counts += c
                 # wait on the last step dispatched (its token is not
                 # served) before reading the served ones back
                 with span(SPAN_READOUT, wave=wave, step=gen_len - 1):
                     toks[-1].block_until_ready()
-                    served = jax.device_get(toks[:gen_len])
+                    served, counts = jax.device_get((toks[:gen_len], counts))
+                # every step done; the next wave prefills a cache of its own
+                _free_cache(inputs)
                 for t, col in enumerate(served):
                     out[active, t] = col[:, 0]
+                if counts:
+                    rows, groups = np.stack(counts).T
+                    self.moe_stats.rows += int(rows.sum(dtype=np.int64))
+                    self.moe_stats.max_group = max(self.moe_stats.max_group,
+                                                   int(groups.max()))
                 stats.decode_steps += gen_len
                 stats.tokens_out += len(active) * gen_len
                 stats.host_reads += len(active) * gen_len

@@ -47,6 +47,8 @@ _PHASES = textwrap.dedent("""
     clock = chip_smoke.Clock(jax)
     chip_smoke.check_serving(clock, "qwen3-0.6b", smoke=True, streams=4,
                              slots=2, prompt_len=1024, gen_len=4)
+    chip_smoke.check_serving(clock, "granite-moe-1b-a400m", smoke=True,
+                             streams=4, slots=2, prompt_len=64, gen_len=4)
     # the real-width kernel cases at toy shapes, interpreted
     small = {"flash_attention": (((1, 256, 2, 128), "bfloat16"),) * 3,
              "mamba2_ssd": (((1, 256, 2, 64), "float32"),
@@ -56,7 +58,10 @@ _PHASES = textwrap.dedent("""
              "rwkv6_wkv": (((1, 32, 2, 64), "float32"),) * 4
                           + (((2, 64), "float32"),),
              "moe_gmm": (((2, 128, 256), "bfloat16"),
-                         ((2, 256, 128), "bfloat16"))}
+                         ((2, 256, 128), "bfloat16")),
+             "moe_ragged_gmm": (((200, 256), "bfloat16"),
+                                ((4, 256, 128), "bfloat16"),
+                                ((4,), "int32"))}
     cases = [dataclasses.replace(c, shapes=small[c.name])
              for c in real_width_cases()]
     chip_smoke.check_kernels(clock, cases, impl="interpret")
@@ -73,6 +78,7 @@ def test_phases_pass_at_smoke_size():
     assert json.loads(proc.stdout.strip().splitlines()[-1]) == {"done": True}
     assert "phase train_sharded" in proc.stdout
     assert "phase prefill_vs_forward" in proc.stdout
+    assert "serve: moe_rows=1088 (no drop: 1088)" in proc.stdout
 
 
 _CACHE_DIR = textwrap.dedent("""

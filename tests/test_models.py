@@ -9,7 +9,7 @@ import pytest
 
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.models import (decode_step, forward_hidden, forward_train,
-                          init_params, prefill)
+                          init_params, lm_logits, prefill)
 
 KEY = jax.random.key(0)
 
@@ -59,12 +59,12 @@ def test_smoke_prefill_decode(arch):
 
 def _full_logits(cfg, params, batch):
     hidden, _, _ = forward_hidden(cfg, params, batch, remat=False)
-    return hidden @ params["lm_head"]
+    return lm_logits(cfg, params, hidden)
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "zamba2-2.7b", "rwkv6-7b",
                                   "whisper-base", "mixtral-8x7b",
-                                  "internvl2-2b"])
+                                  "internvl2-2b", "granite-moe-1b-a400m"])
 def test_decode_matches_teacher_forcing(arch):
     """prefill+decode logits == full forward at the same positions
     (drop-free MoE regime; catches cache/rope/state bugs)."""

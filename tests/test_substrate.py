@@ -283,6 +283,37 @@ def test_jax_serve_engine_serves_the_host_loops_tokens():
     np.testing.assert_array_equal(out, want)
 
 
+def test_jax_serve_engine_frees_each_done_steps_input_cache():
+    """Every decode step's input cache is freed by the engine once the step
+    is done (the last two steps' after the readout, with the wave's final
+    cache).  A buffer the newest cache shares is kept."""
+    from repro.serving import engine as engine_mod
+
+    eng = _small_engine(n_slots=2)
+    seen = []
+    decode = eng.decode_fn
+
+    def decode_fn(params, cache, tok):
+        seen.append(cache)
+        return decode(params, cache, tok)
+
+    eng.decode_fn = decode_fn
+    out = eng.generate(_prompts(2, seed=4), gen_len=5)
+    assert out.shape == (2, 5)
+    deleted = [all(a.is_deleted() for a in jax.tree.leaves(c)) for c in seen]
+    assert deleted == [True] * 5
+    assert eng.stats.decode_steps == 5
+
+    shared = jnp.ones(3)
+    done = {"k": jnp.zeros(2), "s": shared}
+    live = {"k": jnp.zeros(2) + 1, "s": shared}
+    engine_mod._free_cache(done, live)
+    assert done["k"].is_deleted() and not shared.is_deleted()
+    assert not live["k"].is_deleted()
+    engine_mod._free_cache((done, live))
+    assert shared.is_deleted() and live["k"].is_deleted()
+
+
 def test_jax_serve_engine_programs_have_stable_names():
     from repro.models import prefill
 
