@@ -12,9 +12,12 @@ and 32 generated tokens.  It checks that every token is in the
 vocabulary, that a second ``generate`` gives the same tokens, that the
 last-position logits of ``prefill`` match the cache-free pass of
 ``forward_train`` and that the engine's prefill program serves their
-argmax, and, for the MoE, that the engine's counters show every routed row
-computed.  Then it runs each Pallas kernel natively at the widths of
-``repro.kernels.cases`` against its ``ref.py`` oracle.
+argmax, for the MoE that the engine's counters show every routed row
+computed, and that the decode program updates its cache in place (every
+step took its input cache; ``memory_analysis()``: the cache aliased to the
+output, no stacked K among the temporaries).  Then it runs each Pallas
+kernel natively at the widths of ``repro.kernels.cases`` against its
+``ref.py`` oracle.
 
 ``--four-chips``: three steps of qwen3-0.6b training at full width, built as
 ``repro.launch.train`` builds them, on a (data=2, model=2) mesh and then on
@@ -118,6 +121,7 @@ def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
     if not np.array_equal(tokens, again):
         raise AssertionError(
             f"second generate differs in {(tokens != again).sum()} tokens")
+    check_decode_in_place(clock, eng, prompts[:slots])
 
     with clock.phase("prefill_vs_forward"):
         batch = {"tokens": jax.numpy.asarray(prompts[:slots])}
@@ -147,6 +151,40 @@ def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
     if not all(moe_layers and got[j] == best[j] for j in off):
         raise AssertionError(f"prefill_fn's tokens {tok} are not the "
                              f"logits' argmax {lg.argmax(-1)}")
+
+
+def check_decode_in_place(clock, eng, prompts):
+    """The decode program takes its cache (donated) and writes it in place:
+    every step the engine ran took its input cache, the program aliases the
+    whole cache to its output, and its temporaries hold no stacked K or V.
+    The CPU backend computes bf16 in float32 and keeps converted copies of
+    the stack among its temporaries, so that bound is a TPU's only."""
+    import jax
+
+    s = eng.stats
+    if s.cache_donated != s.decode_steps:
+        raise AssertionError(f"{s.cache_donated} of {s.decode_steps} decode "
+                             "steps took their input cache")
+    with clock.phase("decode_memory_analysis"):
+        tok, cache = jax.eval_shape(eng.prefill_fn, eng.params,
+                                    {"tokens": prompts})[:2]
+        mem = eng.decode_fn.lower(eng.params, cache,
+                                  tok).compile().memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    k = cache["layers"]["k"]
+    k_bytes = k.size * k.dtype.itemsize
+    print(f"serve: decode alias_bytes={mem.alias_size_in_bytes} "
+          f"temp_bytes={mem.temp_size_in_bytes} cache_bytes={cache_bytes} "
+          f"stacked_k_bytes={k_bytes} cache_donated={s.cache_donated}")
+    if mem.alias_size_in_bytes < cache_bytes:
+        raise AssertionError("the decode program does not take its cache "
+                             "in place")
+    if jax.default_backend() == "tpu" and \
+            not mem.temp_size_in_bytes < k_bytes:
+        raise AssertionError(f"the decode program's temporaries "
+                             f"({mem.temp_size_in_bytes} B) hold a stacked "
+                             f"cache ({k_bytes} B)")
 
 
 def check_kernels(clock, cases, impl="pallas"):

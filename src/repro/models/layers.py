@@ -336,6 +336,37 @@ def _cache_write(cache: Dict, k: jnp.ndarray, v: jnp.ndarray,
     return {"k": ck, "v": cv}
 
 
+def _ring_slots(Tc: int, cache_pos, S: int):
+    """(j, slot) for each of the last min(S, Tc) of S tokens written from
+    ``cache_pos`` on: the token's index and the ring slot it lands in."""
+    return [(j, (cache_pos + j) % Tc) for j in range(S - min(S, Tc), S)]
+
+
+def _ring_view(ring: jnp.ndarray, new: jnp.ndarray, cache_pos) -> jnp.ndarray:
+    """The ring (B,Tc,...) as ``_cache_write`` would leave it after writing
+    ``new`` (B,S,...), without writing it: a select per new token, which
+    XLA fuses into the reads of the ring."""
+    iota = jnp.arange(ring.shape[1]).reshape((1, -1) + (1,) * (ring.ndim - 2))
+    for j, slot in _ring_slots(ring.shape[1], cache_pos, new.shape[1]):
+        ring = jnp.where(iota == slot, new[:, j:j + 1].astype(ring.dtype),
+                         ring)
+    return ring
+
+
+def ring_update(buf: jnp.ndarray, new: jnp.ndarray, cache_pos,
+                axis: int) -> jnp.ndarray:
+    """Write the tokens of ``new`` into the ring buffer ``buf`` (its ring
+    along ``axis``, ``new``'s tokens along the same axis) as
+    ``_cache_write`` does, one ``dynamic_update_slice`` per token: on a
+    donated ``buf`` XLA writes in place, touching the new tokens only."""
+    start = [0] * buf.ndim
+    for j, slot in _ring_slots(buf.shape[axis], cache_pos, new.shape[axis]):
+        start[axis] = slot
+        tok = jax.lax.slice_in_dim(new, j, j + 1, axis=axis)
+        buf = jax.lax.dynamic_update_slice(buf, tok.astype(buf.dtype), start)
+    return buf
+
+
 def _cache_slot_positions(Tc: int, cache_pos, S: int) -> jnp.ndarray:
     """Absolute position held by ring slot i after writing S tokens:
     p(i) = last - ((last - i) mod Tc), last = cache_pos + S - 1; -1 if
@@ -367,7 +398,9 @@ def multihead_attention(
     q_scale: Optional[float] = None,  # q times this after RoPE (None: 1)
     sc=lambda x, kind=None: x,      # sharding-constraint hook
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
-    """Returns (output (B,S,d_model), updated cache).
+    """Returns (output (B,S,d_model), cache out): the updated cache, or in
+    self-attention decode the new tokens' {"k","v"} (B,S,n_kv,d_head), which
+    the caller writes into the ring (``ring_update``).
 
     Every path scales scores by 1/sqrt(d_head); a ``q_scale`` of
     ``m * sqrt(d_head)`` makes that a score scale of ``m``.
@@ -376,8 +409,9 @@ def multihead_attention(
       train   - cache is None: attend within the sequence.
       prefill - cache given, decode=False: attend within the sequence,
                 write the last min(S, cache_len) tokens into the ring.
-      decode  - cache given, decode=True: write current token(s), attend
-                over the whole ring buffer.
+      decode  - cache given, decode=True: attend over the whole ring
+                buffer as it reads with the current token(s) written, and
+                return those tokens' k/v, not a new ring.
     """
     B, S, _ = x.shape
     G = n_heads // n_kv
@@ -415,18 +449,20 @@ def multihead_attention(
         if q_scale is not None:
             q = q * jnp.asarray(q_scale, q.dtype)
 
-        if cache is not None:
+        if cache is not None and decode:
+            # the ring is read with the new tokens selected into their
+            # slots, never written here: the caller writes them
+            new_cache = {"k": k, "v": v}
+            k_full = _ring_view(cache["k"], k, cache_pos)
+            v_full = _ring_view(cache["v"], v, cache_pos)
+            k_pos = _cache_slot_positions(cache["k"].shape[1], cache_pos, S)
+            q_pos = cache_pos + jnp.arange(S)
+        elif cache is not None:
             new_cache = _cache_write(cache, k, v, cache_pos)
-            if decode:
-                Tc = cache["k"].shape[1]
-                k_full, v_full = new_cache["k"], new_cache["v"]
-                k_pos = _cache_slot_positions(Tc, cache_pos, S)
-                q_pos = cache_pos + jnp.arange(S)
-            else:
-                # prefill: attend within the sequence
-                k_full, v_full = k, v
-                k_pos = positions[:Tsrc]
-                q_pos = positions
+            # prefill: attend within the sequence
+            k_full, v_full = k, v
+            k_pos = positions[:Tsrc]
+            q_pos = positions
         else:
             new_cache = None
             k_full, v_full = k, v

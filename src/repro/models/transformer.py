@@ -308,7 +308,12 @@ def _apply_rwkv_block(cfg, p, x, cache, *, decode, sc):
 def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
            cache_pos, *, decode: bool, cross_src, sc, remat: bool,
            moe_offset=None):
-    """Run the decoder stack.  caches: stacked per-layer cache or None."""
+    """Run the decoder stack.  caches: stacked per-layer cache or None.
+
+    In decode an attention layer's ring is read-only in the scan: each layer
+    hands back its new tokens' k/v, written into the stacked ring after the
+    scan, so a step over a donated cache writes those tokens and copies no
+    cache.  mamba2/rwkv6 states are rewritten whole every step."""
     kind = cfg.block_pattern[0]
 
     def unit(carry, xs):
@@ -343,6 +348,11 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
                     cfg, sp, x, positions, scache, cache_pos,
                     decode=decode, causal=True, cross_src=None,
                     cross_cache=None, sc=sc)
+                if decode:      # the block returned the new tokens' k/v
+                    new_scache = jax.tree.map(
+                        lambda buf, new: L.ring_update(buf, new, cache_pos,
+                                                       axis=1),
+                        scache, new_scache)
                 if shared_cache is not None:
                     shared_cache = jax.tree.map(
                         lambda buf, upd: buf.at[inv].set(upd),
@@ -373,6 +383,11 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
 
     new_caches = None
     if caches is not None:
+        if decode and kind in ("attn", "moe"):
+            # ys holds the new tokens (L, B, S, kv, dh): the ring's T is axis 2
+            new_layer_caches = jax.tree.map(
+                lambda buf, new: L.ring_update(buf, new, cache_pos, axis=2),
+                layer_caches, new_layer_caches)
         new_caches = dict(caches)
         new_caches["layers"] = new_layer_caches
         if cfg.is_encdec:

@@ -904,6 +904,12 @@ class EngineStats:
     host_reads: int = 0       # tokens brought to the host, one per token
     streams_parked: int = 0   # not admitted in a call's first pass
 
+    def __post_init__(self):
+        # decode dispatches that took their input cache (donated).  Not a
+        # field: the fields are the counters bench/phases.py reports, a set
+        # the benchmark's own tests hold fixed
+        self.cache_donated = 0
+
 
 @dataclass
 class MoEStats:
@@ -914,21 +920,6 @@ class MoEStats:
 
     rows: int = 0
     max_group: int = 0
-
-
-def _free_cache(done, keep=None) -> None:
-    """Free the buffers of ``done``, a cache no step will read again, now,
-    before the next step allocates its own: left to the garbage collector,
-    the runtime frees them once it notes the last reader's end, on a TPU
-    sometimes only after the next allocation, so the device's peak held a
-    cache more in some runs and not in others.  Buffers that ``keep`` (the
-    newest cache) shares, such as a part a step returns unchanged, stay."""
-    import jax
-
-    kept = {id(a) for a in jax.tree.leaves(keep)}
-    for a in jax.tree.leaves(done):
-        if id(a) not in kept and not a.is_deleted():
-            a.delete()
 
 
 class JaxServeEngine:
@@ -978,7 +969,9 @@ class JaxServeEngine:
             return outputs(*prefill(cfg, p, b, max_len=max_len,
                                     return_aux=moe))
 
-        self.decode_fn = jax.jit(serve_decode)
+        # a step takes its input cache (donated) and writes the new token's
+        # k/v into it in place
+        self.decode_fn = jax.jit(serve_decode, donate_argnums=(1,))
         self.prefill_fn = jax.jit(serve_prefill)
 
     def generate(self, prompts: "np.ndarray", gen_len: int
@@ -987,11 +980,12 @@ class JaxServeEngine:
         beyond the active limit are parked and admitted as slots free.
 
         Every admitted stream runs ``gen_len`` steps in its wave.  Each step
-        is fed the previous step's token on the device, the host keeps up to
-        two steps queued without reading any, and it reads the served tokens
-        back once, after the wave's last step is done, with the MoE
-        counts of the wave's steps.  It frees each cache itself once no
-        step will read it again."""
+        is fed the previous step's token on the device and takes the previous
+        step's cache (donated), so the host queues the wave's steps without
+        waiting and without a cache per step.  It reads the served tokens
+        back once, after the wave's last step is done, with the MoE counts
+        of the wave's steps, and frees the wave's cache before the next
+        prefill."""
         import jax
         import jax.numpy as jnp
         from jax.profiler import TraceAnnotation as span
@@ -1026,18 +1020,13 @@ class JaxServeEngine:
                     batch = {"tokens": jnp.asarray(prompts[active])}
                     tok, cache, *counts = self.prefill_fn(self.params, batch)
                 toks = [tok]
-                inputs = [cache]        # caches a step may still read
                 for t in range(gen_len):
-                    if t >= 2:
-                        # step t-2 done: at most two steps in flight, as
-                        # each holds a fresh copy of the cache; its input
-                        # freed before step t allocates
-                        toks[t - 1].block_until_ready()
-                        _free_cache(inputs.pop(0), cache)
+                    old = cache
                     with span(SPAN_DISPATCH, wave=wave, step=t):
                         tok, cache, *c = self.decode_fn(self.params, cache,
                                                         tok)
-                    inputs.append(cache)
+                    donated = jax.tree.leaves(old)[0].is_deleted()
+                    stats.cache_donated += int(donated)
                     toks.append(tok)
                     counts += c
                 # wait on the last step dispatched (its token is not
@@ -1045,8 +1034,11 @@ class JaxServeEngine:
                 with span(SPAN_READOUT, wave=wave, step=gen_len - 1):
                     toks[-1].block_until_ready()
                     served, counts = jax.device_get((toks[:gen_len], counts))
-                # every step done; the next wave prefills a cache of its own
-                _free_cache(inputs)
+                # every step done: free the wave's cache now, before the next
+                # wave's prefill allocates its own (left to the runtime, a
+                # buffer was sometimes freed after that allocation)
+                for a in jax.tree.leaves(cache):
+                    a.delete()
                 for t, col in enumerate(served):
                     out[active, t] = col[:, 0]
                 if counts:

@@ -79,6 +79,7 @@ def test_phases_pass_at_smoke_size():
     assert "phase train_sharded" in proc.stdout
     assert "phase prefill_vs_forward" in proc.stdout
     assert "serve: moe_rows=1088 (no drop: 1088)" in proc.stdout
+    assert proc.stdout.count("serve: decode alias_bytes=") == 2
 
 
 _CACHE_DIR = textwrap.dedent("""

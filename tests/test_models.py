@@ -98,9 +98,12 @@ def test_decode_matches_teacher_forcing(arch):
     assert max(errs) < 1e-4, errs
 
 
-def test_sliding_window_ring_buffer():
+@pytest.mark.parametrize("step", ["eager", "donated"])
+def test_sliding_window_ring_buffer(step):
     """SWA decode far beyond the window uses the ring buffer correctly:
-    logits must keep matching teacher forcing past the wrap point."""
+    logits must keep matching teacher forcing past the wrap point, also
+    through the jitted step that takes its cache (donated) and writes the
+    new token into it in place."""
     cfg = dataclasses.replace(get_smoke_config("mixtral-8x7b"),
                               dtype="float32", sliding_window=16,
                               moe_capacity_factor=8.0)
@@ -112,10 +115,13 @@ def test_sliding_window_ring_buffer():
                      np.float32)
     logits, cache = prefill(cfg, params, {"tokens": toks[:, :S]},
                             max_len=S + EXTRA)
+    dec = (decode_step if step == "eager" else
+           jax.jit(decode_step, static_argnums=0, donate_argnums=2))
     errs = []
     for t in range(EXTRA):
-        logits, cache = decode_step(cfg, params, cache,
-                                    toks[:, S + t][:, None])
+        old = cache
+        logits, cache = dec(cfg, params, cache, toks[:, S + t][:, None])
+        assert old["layers"]["k"].is_deleted() == (step == "donated")
         errs.append(np.abs(np.asarray(logits[:, 0], np.float32)
                            - ref[:, S + t]).max())
     assert max(errs) < 1e-4, errs

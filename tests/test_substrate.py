@@ -1,5 +1,6 @@
 """Substrate tests: data pipeline, optimizer, checkpoint, runtime, serving."""
 
+import dataclasses
 import os
 import threading
 import time
@@ -284,11 +285,9 @@ def test_jax_serve_engine_serves_the_host_loops_tokens():
 
 
 def test_jax_serve_engine_frees_each_done_steps_input_cache():
-    """Every decode step's input cache is freed by the engine once the step
-    is done (the last two steps' after the readout, with the wave's final
-    cache).  A buffer the newest cache shares is kept."""
-    from repro.serving import engine as engine_mod
-
+    """Every decode step's input cache is gone once the step is dispatched:
+    the step takes it (donated) and updates it in place, and the engine
+    counts each dispatch that took its cache."""
     eng = _small_engine(n_slots=2)
     seen = []
     decode = eng.decode_fn
@@ -303,15 +302,38 @@ def test_jax_serve_engine_frees_each_done_steps_input_cache():
     deleted = [all(a.is_deleted() for a in jax.tree.leaves(c)) for c in seen]
     assert deleted == [True] * 5
     assert eng.stats.decode_steps == 5
+    assert eng.stats.cache_donated == eng.stats.decode_steps
 
-    shared = jnp.ones(3)
-    done = {"k": jnp.zeros(2), "s": shared}
-    live = {"k": jnp.zeros(2) + 1, "s": shared}
-    engine_mod._free_cache(done, live)
-    assert done["k"].is_deleted() and not shared.is_deleted()
-    assert not live["k"].is_deleted()
-    engine_mod._free_cache((done, live))
-    assert shared.is_deleted() and live["k"].is_deleted()
+
+@pytest.mark.parametrize("arch,window", [("qwen3-0.6b", 0),
+                                         ("granite-moe-1b-a400m", 0),
+                                         ("mixtral-8x7b", 1024)])
+def test_jax_serve_engine_decode_takes_its_cache_in_place(arch, window):
+    """The decode program aliases the whole cache to its output and holds no
+    stacked K among its temporaries: the scan reads the ring, and the new
+    tokens are written into the donated stack after it.  float32 and four
+    layers: the CPU backend computes bf16 in float32 (with converted copies
+    of the stack), and keeps a relayout of each layer's K and V for the
+    attention's dots, which with two layers would reach a stacked K."""
+    from repro.models import init_params, prefill
+    from repro.serving.engine import JaxServeEngine
+
+    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32",
+                              n_layers=4)
+    if window:
+        cfg = dataclasses.replace(cfg, sliding_window=window)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    eng = JaxServeEngine(cfg, params, n_slots=4, max_len=2048)
+    batch = {"tokens": jax.ShapeDtypeStruct((4, 8), jnp.int32)}
+    tok, cache = jax.eval_shape(eng.prefill_fn, params, batch)[:2]
+    mem = eng.decode_fn.lower(params, cache, tok).compile().memory_analysis()
+    k = cache["layers"]["k"]
+    if window:
+        assert k.shape[2] == window       # the ring holds the window
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < k.size * k.dtype.itemsize
 
 
 def test_jax_serve_engine_programs_have_stable_names():
