@@ -15,9 +15,10 @@ last-position logits of ``prefill`` match the cache-free pass of
 argmax, for the MoE that the engine's counters show every routed row
 computed, and that the decode program updates its cache in place (every
 step took its input cache; ``memory_analysis()``: the cache aliased to the
-output, no stacked K among the temporaries).  Then it runs each Pallas
-kernel natively at the widths of ``repro.kernels.cases`` against its
-``ref.py`` oracle.
+output, no stacked K among the temporaries).  Then it runs each case of
+``repro.kernels.cases`` natively against its ``ref.py`` oracle: the three
+Pallas kernels of ``repro.kernels`` and the MoE's megablox ``gmm`` at
+granite-moe-1b-a400m's and mixtral-8x7b's widths.
 
 ``--four-chips``: three steps of qwen3-0.6b training at full width, built as
 ``repro.launch.train`` builds them, on a (data=2, model=2) mesh and then on
@@ -209,7 +210,6 @@ def check_sharded_training(clock, cfg, devices, *, model, batch, seq, steps):
     """Losses of ``steps`` train steps on a (data, model) mesh over
     ``devices`` against the same steps on ``devices[0]`` alone."""
     import jax
-    import jax.numpy as jnp
 
     from repro.config import OptimizerConfig
     from repro.launch.mesh import make_host_mesh
@@ -226,11 +226,10 @@ def check_sharded_training(clock, cfg, devices, *, model, batch, seq, steps):
         mesh = make_host_mesh(model=model_axis, devices=devs)
         with clock.phase(f"train_{name}"):
             step_fn, params, opt = build_sharded_train(cfg, mesh, opt_cfg)
-            compiled = step_fn.lower(params, opt, data, jnp.int32(0)).compile()
+            compiled = step_fn.lower(params, opt, data).compile()
             losses = []
-            for i in range(steps):
-                params, opt, metrics = compiled(params, opt, data,
-                                                jnp.int32(i))
+            for _ in range(steps):
+                params, opt, metrics = compiled(params, opt, data)
                 losses.append(float(metrics["loss"]))
         per_dev = {}
         for leaf in jax.tree.leaves(params):

@@ -40,7 +40,7 @@ def build(arch_cfg, steps: int):
     opt = jax.device_put(opt, o_sh)
     opt_cfg = OptimizerConfig(lr=3e-4, warmup_steps=20, total_steps=steps)
     step = jax.jit(make_train_step(arch_cfg, opt_cfg, rules),
-                   in_shardings=(p_sh, o_sh, None, None),
+                   in_shardings=(p_sh, o_sh, None),
                    out_shardings=(p_sh, o_sh, None),
                    donate_argnums=(0, 1))
     return params, opt, step
@@ -83,7 +83,7 @@ def main() -> None:
         if i >= half:
             break
         t0 = time.perf_counter()
-        params, opt, metrics = step(params, opt, batch, jnp.int32(i))
+        params, opt, metrics = step(params, opt, batch)
         dt = time.perf_counter() - t0
         losses.append(float(metrics["loss"]))
         straggler.observe({w: dt * (1 + 0.05 * w) for w in range(4)})
@@ -108,7 +108,7 @@ def main() -> None:
     for i, batch in iter(pipe2):
         if i >= args.steps:
             break
-        params2, opt2, metrics = step(params2, opt2, batch, jnp.int32(i))
+        params2, opt2, metrics = step(params2, opt2, batch)
         losses.append(float(metrics["loss"]))
         if (i + 1) % 10 == 0:
             print(f"step {i+1:4d} loss {losses[-1]:.4f} (resumed)")

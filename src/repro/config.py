@@ -52,15 +52,6 @@ class ModelConfig:
     n_experts: int = 0
     n_experts_active: int = 0
     moe_d_ff: int = 0
-    moe_capacity_factor: float = 1.25
-    # GCR-MoE (beyond-paper, DESIGN.md section 2): concurrency-restriction-style
-    # token admission with rotating priority for long-term fairness.
-    gcr_moe: bool = False
-    gcr_moe_rotate_every: int = 64  # steps between priority rotations
-    # dropless routing: every (token, expert) row is computed, sorted by
-    # expert and run as one grouped matmul over ragged groups (no capacity,
-    # so no GCR admission either)
-    moe_dropless: bool = False
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -257,17 +248,6 @@ class OptimizerConfig:
     grad_clip: float = 1.0
     zero1: bool = True             # shard optimizer state over the data axis
     grad_compression: str = "none"  # none | int8  (cross-pod hop)
-
-
-@dataclass(frozen=True)
-class RuntimeConfig:
-    remat: str = "block"           # none | block | full
-    scan_layers: bool = True
-    attn_impl: str = "xla"         # xla | pallas (pallas = TPU target path)
-    microbatches: int = 1          # grad accumulation
-    checkpoint_every: int = 200
-    checkpoint_dir: str = "/tmp/repro_ckpt"
-    seed: int = 0
 
 
 @dataclass(frozen=True)

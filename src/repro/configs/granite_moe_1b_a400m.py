@@ -22,7 +22,6 @@ CONFIG = ModelConfig(
     n_experts=32,
     n_experts_active=8,
     moe_d_ff=512,
-    moe_dropless=True,
     norm_eps=1e-6,
     embedding_multiplier=12.0,
     attention_multiplier=0.015625,

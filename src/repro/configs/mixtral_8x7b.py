@@ -1,4 +1,4 @@
-"""mixtral-8x7b [moe]: 8 experts top-2, sliding-window attention
+"""mixtral-8x7b [moe]: 8 experts top-2, dropless, sliding-window attention
 [arXiv:2401.04088].  32L d_model=4096 32H(kv=8) expert d_ff=14336
 vocab=32000, window=4096."""
 
@@ -20,7 +20,6 @@ CONFIG = ModelConfig(
     n_experts=8,
     n_experts_active=2,
     moe_d_ff=14336,
-    gcr_moe=True,
 )
 
 SMOKE = dataclasses.replace(

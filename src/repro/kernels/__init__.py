@@ -1,9 +1,11 @@
-"""Pallas TPU kernels, each as ``kernel.py`` + ``ops.py`` + ``ref.py``.
+"""Pallas TPU kernels, each as ``kernel.py`` + ``ops.py`` + ``ref.py``;
+``moe_gmm`` has no ``kernel.py``: its op calls megablox's ``gmm``.
 
 Every op takes ``impl``: ``pallas`` runs the kernel natively, ``interpret``
 runs it in the Pallas interpreter (how the tests check it on a CPU), ``ref``
 runs the plain oracle, and ``auto`` means ``pallas`` on a TPU and is an
-error anywhere else, so no caller falls back to the interpreter unawares.
+error anywhere else, so no caller falls back to the interpreter unawares;
+``moe_gmm``'s ``auto`` is ``ref`` off a TPU, because the model calls it.
 """
 
 import jax

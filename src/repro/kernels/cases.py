@@ -2,8 +2,8 @@
 
 The TPU compile tests (tests/test_tpu_compile.py) compile these shapes for
 a described v5e, and ``chip_smoke.py`` runs them on the chip against the
-``ref.py`` oracles.  ``moe_gmm`` (fixed capacity) is off the model's path;
-``moe_ragged_gmm`` is the dropless MoE's grouped matmul.
+``ref.py`` oracles.  ``moe_ragged_gmm`` and ``moe_ragged_gmm_mixtral``
+are the dropless MoE's grouped matmul.
 """
 
 from __future__ import annotations
@@ -19,8 +19,8 @@ from .flash_attention.ops import flash_attention
 from .flash_attention.ref import attention_ref
 from .mamba2_ssd.ops import ssd
 from .mamba2_ssd.ref import ssd_ref
-from .moe_gmm.ops import grouped_matmul, ragged_matmul
-from .moe_gmm.ref import gmm_ref, ragged_ref
+from .moe_gmm.ops import ragged_matmul
+from .moe_gmm.ref import ragged_ref
 from .rwkv6_wkv.ops import wkv
 from .rwkv6_wkv.ref import wkv_ref
 
@@ -81,20 +81,17 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
         w = jnp.exp(-jnp.exp(_normal(k[3], *shapes[3], 0.5) - 2))
         return [r, kk, v, w, _normal(k[4], *shapes[4], 0.1)]
 
-    # grouped matmul: mixtral-8x7b experts, 512 tokens of capacity each
-    E, D, F = mixtral.n_experts, mixtral.d_model, mixtral.moe_d_ff
-    gmm_shapes = (((E, 512, D), "bfloat16"), ((E, D, F), "bfloat16"))
-
-    def gmm_draw(key, shapes):
-        k = jax.random.split(key, 2)
-        return [_normal(k[0], *shapes[0]), _normal(k[1], *shapes[1], 0.05)]
-
     # ragged grouped matmul: granite-moe-1b-a400m's gate projection over a
     # 1024-token prompt's rows (k per token), routed to the first three
     # quarters of the experts (the rest empty)
     Eg, Dg, Fg = granite.n_experts, granite.d_model, granite.moe_d_ff
     ragged_shapes = (((1024 * granite.n_experts_active, Dg), "bfloat16"),
                      ((Eg, Dg, Fg), "bfloat16"), ((Eg,), "int32"))
+
+    # and mixtral-8x7b's, over a 1024-token prompt's rows (k = 2 per token)
+    Em, Dm, Fm = mixtral.n_experts, mixtral.d_model, mixtral.moe_d_ff
+    mixtral_shapes = (((1024 * mixtral.n_experts_active, Dm), "bfloat16"),
+                      ((Em, Dm, Fm), "bfloat16"), ((Em,), "int32"))
 
     def ragged_draw(key, shapes):
         k = jax.random.split(key, 3)
@@ -112,7 +109,8 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
         KernelCase("mamba2_ssd", ssd, ssd_ref, ssd_shapes, ssd_draw),
         KernelCase("rwkv6_wkv", wkv, wkv_ref,
                    (rkvw,) * 4 + (((Hr, Pr), "float32"),), wkv_draw),
-        KernelCase("moe_gmm", grouped_matmul, gmm_ref, gmm_shapes, gmm_draw),
         KernelCase("moe_ragged_gmm", ragged_matmul, ragged_ref,
                    ragged_shapes, ragged_draw),
+        KernelCase("moe_ragged_gmm_mixtral", ragged_matmul, ragged_ref,
+                   mixtral_shapes, ragged_draw),
     )

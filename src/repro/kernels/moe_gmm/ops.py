@@ -1,9 +1,6 @@
-"""Public ops: grouped expert matmuls with kernel/reference dispatch.
-
-``grouped_matmul`` takes fixed-capacity ``(E, C, D)`` buffers (the capacity
-MoE).  ``ragged_matmul`` takes rows sorted by expert with one count per
-expert (the dropless MoE): megablox's ``gmm`` on a TPU, ``jax.lax.ragged_dot``
-elsewhere.
+"""Public op: grouped expert matmuls over rows sorted by expert, with one
+count per expert (the dropless MoE): megablox's ``gmm`` on a TPU,
+``jax.lax.ragged_dot`` elsewhere.
 """
 
 from __future__ import annotations
@@ -13,19 +10,10 @@ import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import megablox
 
 from .. import interpret_mode
-from .kernel import gmm
-from .ref import gmm_ref, ragged_ref
 
 MAX_TILE_M = 512
 MIN_TILE_M = 16      # a bf16 sublane tile
 MAX_TILE_KN = 1024
-
-
-def grouped_matmul(x, w, *, impl: str = "auto"):
-    """impl: auto | pallas | interpret | ref."""
-    if impl == "ref":
-        return gmm_ref(x, w)
-    return gmm(x, w, interpret=interpret_mode(impl))
 
 
 def row_tile(rows: int, groups: int) -> int:

@@ -145,11 +145,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 accum_dtype=jnp.dtype(over.get("accum_dtype", "float32")))
             jitted = jax.jit(
                 step_fn,
-                in_shardings=(p_sh, o_sh, batch_sh, None),
+                in_shardings=(p_sh, o_sh, batch_sh),
                 out_shardings=(p_sh, o_sh, None),
                 donate_argnums=(0, 1))
-            lowered = jitted.lower(params, opt, batch,
-                                   jax.ShapeDtypeStruct((), jnp.int32))
+            lowered = jitted.lower(params, opt, batch)
         elif shape.kind == "prefill":
             params, _ = train_state_shapes(cfg)
             p_sh = rules.param_shardings(params)

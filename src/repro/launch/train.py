@@ -37,7 +37,7 @@ def build_sharded_train(cfg, mesh, opt_cfg: OptimizerConfig, *, seed: int = 0,
     and the jitted step over it.  The state is initialised straight into its
     shards, so no device holds it whole.
 
-    Returns (step_fn, params, opt); ``step_fn(params, opt, batch, step)``
+    Returns (step_fn, params, opt); ``step_fn(params, opt, batch)``
     donates params and opt and returns (params, opt, metrics)."""
     rules = ShardingRules(cfg, mesh)
     p_shape, _ = train_state_shapes(cfg)
@@ -49,7 +49,7 @@ def build_sharded_train(cfg, mesh, opt_cfg: OptimizerConfig, *, seed: int = 0,
                           out_shardings=(p_sh, o_sh))(jax.random.key(seed))
     step_fn = jax.jit(
         make_train_step(cfg, opt_cfg, rules, microbatches=microbatches),
-        in_shardings=(p_sh, o_sh, None, None),
+        in_shardings=(p_sh, o_sh, None),
         out_shardings=(p_sh, o_sh, None),
         donate_argnums=(0, 1))
     return step_fn, params, opt
@@ -111,7 +111,7 @@ def main() -> None:
         for i, batch in iter(pipe):
             if i >= args.steps:
                 break
-            params, opt, metrics = step_fn(params, opt, batch, jnp.int32(i))
+            params, opt, metrics = step_fn(params, opt, batch)
             tokens_done += args.batch * args.seq
             if (i + 1) % 10 == 0:
                 dt = time.perf_counter() - t0

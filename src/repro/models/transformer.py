@@ -212,7 +212,7 @@ def _scaled(x, m: Optional[float]):
 
 
 def _apply_attn_block(cfg, p, x, positions, cache, cache_pos, *, decode,
-                      causal, cross_src, cross_cache, sc, moe_offset=None):
+                      causal, cross_src, cross_cache, sc):
     """attn (+cross) (+mlp/moe) block. Returns (x, new_cache, new_cross, aux)."""
     aux: Dict[str, jnp.ndarray] = {}
     res = cfg.residual_multiplier
@@ -239,12 +239,8 @@ def _apply_attn_block(cfg, p, x, positions, cache, cache_pos, *, decode,
 
     h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if "moe" in p:
-        moe_out, aux = MOE.moe_mlp(
-            p["moe"], h2, n_experts=cfg.n_experts,
-            top_k=cfg.n_experts_active,
-            capacity_factor=cfg.moe_capacity_factor,
-            gcr_admission=cfg.gcr_moe,
-            priority_offset=moe_offset, dropless=cfg.moe_dropless, sc=sc)
+        moe_out, aux = MOE.moe_mlp(p["moe"], h2, n_experts=cfg.n_experts,
+                                   top_k=cfg.n_experts_active)
         x = sc(x + _scaled(moe_out, res), "residual")
     else:
         x = sc(x + _scaled(L.mlp(p["mlp"], h2), res), "residual")
@@ -306,8 +302,7 @@ def _apply_rwkv_block(cfg, p, x, cache, *, decode, sc):
 
 
 def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
-           cache_pos, *, decode: bool, cross_src, sc, remat: bool,
-           moe_offset=None):
+           cache_pos, *, decode: bool, cross_src, sc, remat: bool):
     """Run the decoder stack.  caches: stacked per-layer cache or None.
 
     In decode an attention layer's ring is read-only in the scan: each layer
@@ -324,7 +319,7 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
             x, new_lcache, new_lcross, aux = _apply_attn_block(
                 cfg, lp, x, positions, lcache, cache_pos,
                 decode=decode, causal=True, cross_src=cross_src,
-                cross_cache=lcross, sc=sc, moe_offset=moe_offset)
+                cross_cache=lcross, sc=sc)
         elif kind == "mamba2":
             x, new_lcache = _apply_mamba_block(cfg, lp, x, lcache,
                                                decode=decode, sc=sc)
@@ -445,8 +440,7 @@ def _embed_inputs(cfg: ModelConfig, params: Params, batch: Dict,
 
 
 def forward_hidden(cfg: ModelConfig, params: Params, batch: Dict,
-                   sc: Callable = _id_sc, remat: bool = True,
-                   moe_offset=None):
+                   sc: Callable = _id_sc, remat: bool = True):
     """Full-sequence pass without caches, the body of ``forward_train``.
 
     Returns (final-normed hidden states (B, S, D), loss mask or None, aux);
@@ -459,8 +453,7 @@ def forward_hidden(cfg: ModelConfig, params: Params, batch: Dict,
         cross_src = _encode(cfg, params, batch["frames"], sc, remat)
 
     x, _, aux = _stack(cfg, params, x, positions, None, None,
-                       decode=False, cross_src=cross_src, sc=sc, remat=remat,
-                       moe_offset=moe_offset)
+                       decode=False, cross_src=cross_src, sc=sc, remat=remat)
     return L.rms_norm(x, params["final_norm"], cfg.norm_eps), mask, aux
 
 
@@ -476,10 +469,9 @@ def lm_logits(cfg: ModelConfig, params: Params, hidden, sc: Callable = _id_sc):
 
 
 def forward_train(cfg: ModelConfig, params: Params, batch: Dict,
-                  sc: Callable = _id_sc, remat: bool = True,
-                  moe_offset=None):
+                  sc: Callable = _id_sc, remat: bool = True):
     """Full-sequence forward; returns (loss, metrics)."""
-    x, mask, aux = forward_hidden(cfg, params, batch, sc, remat, moe_offset)
+    x, mask, aux = forward_hidden(cfg, params, batch, sc, remat)
 
     targets = batch["targets"]
     if cfg.frontend == "vision_stub":

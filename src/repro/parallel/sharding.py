@@ -279,17 +279,6 @@ class ShardingRules:
                 return x
             spec = P(self._batch_axis(x.shape[0]), None,
                      self._maybe(x.shape[2], self.tp), None)
-        elif kind == "moe_buf":
-            # (B, E, C, D) grouped expert capacity buffer: groups on dp,
-            # experts on model (EP) when E divides the axis, else TP on D
-            if x.ndim != 4:
-                return x
-            b_axis = self._batch_axis(x.shape[0])
-            if x.shape[1] % self.tp_size == 0:
-                spec = P(b_axis, self.tp, None, None)
-            else:
-                spec = P(b_axis, None, None,
-                         self._maybe(x.shape[3], self.tp))
         else:
             return x
         return jax.lax.with_sharding_constraint(

@@ -69,7 +69,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
                     rules: Optional[ShardingRules] = None,
                     remat: bool = True, donate: bool = True,
                     microbatches: int = 1, accum_dtype=jnp.float32):
-    """Returns train_step(params, opt_state, batch, step) ->
+    """Returns train_step(params, opt_state, batch) ->
         (params, opt_state, metrics).
 
     ``microbatches > 1`` runs gradient accumulation over batch splits
@@ -78,15 +78,8 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
     microbatch - the standard lever when a cell exceeds HBM."""
     sc = rules.constrain if rules is not None else (lambda x, kind=None: x)
 
-    def loss_fn(params, batch, step):
-        moe_offset = None
-        if cfg.gcr_moe:
-            # GCR-MoE fairness rotation: priority origin moves every
-            # gcr_moe_rotate_every steps (the THRESHOLD-promotion analogue).
-            stride = 4099  # prime stride: co-prime with token counts
-            moe_offset = (step // cfg.gcr_moe_rotate_every) * stride
-        return T.forward_train(cfg, params, batch, sc=sc, remat=remat,
-                               moe_offset=moe_offset)
+    def loss_fn(params, batch):
+        return T.forward_train(cfg, params, batch, sc=sc, remat=remat)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -100,9 +93,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
             lambda g, s: jax.lax.with_sharding_constraint(
                 g, rules.sharding(s)), grads, specs)
 
-    def train_step(params, opt_state, batch, step):
+    def train_step(params, opt_state, batch):
         if microbatches == 1:
-            (loss, metrics), grads = grad_fn(params, batch, step)
+            (loss, metrics), grads = grad_fn(params, batch)
             grads = _pin(grads, params)
         else:
             mb = jax.tree.map(
@@ -114,7 +107,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
 
             def acc(carry, b):
                 gsum, lsum = carry
-                (l, m), g = grad_fn(params, b, step)
+                (l, m), g = grad_fn(params, b)
                 g = _pin(g, params)
                 gsum = jax.tree.map(
                     lambda a, x: a + x.astype(accum_dtype), gsum, g)

@@ -57,11 +57,12 @@ _PHASES = textwrap.dedent("""
                             ((1, 256, 64), "float32")),
              "rwkv6_wkv": (((1, 32, 2, 64), "float32"),) * 4
                           + (((2, 64), "float32"),),
-             "moe_gmm": (((2, 128, 256), "bfloat16"),
-                         ((2, 256, 128), "bfloat16")),
              "moe_ragged_gmm": (((200, 256), "bfloat16"),
                                 ((4, 256, 128), "bfloat16"),
-                                ((4,), "int32"))}
+                                ((4,), "int32")),
+             "moe_ragged_gmm_mixtral": (((160, 512), "bfloat16"),
+                                        ((4, 512, 256), "bfloat16"),
+                                        ((4,), "int32"))}
     cases = [dataclasses.replace(c, shapes=small[c.name])
              for c in real_width_cases()]
     chip_smoke.check_kernels(clock, cases, impl="interpret")

@@ -9,8 +9,8 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.mamba2_ssd.ops import ssd
 from repro.kernels.mamba2_ssd.ref import ssd_ref
-from repro.kernels.moe_gmm.ops import grouped_matmul
-from repro.kernels.moe_gmm.ref import gmm_ref
+from repro.kernels.moe_gmm.ops import ragged_matmul
+from repro.kernels.moe_gmm.ref import ragged_ref
 from repro.kernels.rwkv6_wkv.ops import wkv
 from repro.kernels.rwkv6_wkv.ref import wkv_ref
 
@@ -79,14 +79,23 @@ def test_wkv_sweep(B, S, H, P, chunk):
                                atol=5e-3, rtol=5e-3)
 
 
-@pytest.mark.parametrize("E,C,D,F", [(4, 128, 256, 128), (2, 256, 512, 256)])
+@pytest.mark.parametrize("sizes,K,N", [
+    ([40, 0, 70, 90], 2048, 2048),     # an empty group, ragged edges
+    ([13, 27, 0], 2048, 3072),         # rows under one row tile
+], ids=["four_groups", "three_groups"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_gmm_sweep(E, C, D, F, dtype):
+def test_ragged_gmm_sweep(sizes, K, N, dtype):
+    """megablox's ``gmm`` (interpreted) against the oracle, with K and N
+    wider than one of its 1024-wide tiles, so it accumulates over K tiles
+    and writes several N tiles."""
     ks = jax.random.split(KEY, 2)
-    x = jax.random.normal(ks[0], (E, C, D), dtype)
-    w = jax.random.normal(ks[1], (E, D, F), dtype) * 0.05
-    got = grouped_matmul(x, w, impl="interpret")
-    want = gmm_ref(x, w)
+    x = jax.random.normal(ks[0], (sum(sizes), K), dtype)
+    w = jax.random.normal(ks[1], (len(sizes), K, N), dtype) * 0.05
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = ragged_matmul(x, w, gs, impl="interpret")
+    want = ragged_ref(x, w, gs)
+    assert got.shape == want.shape == (sum(sizes), N)
+    assert got.dtype == dtype
     scale = float(jnp.abs(want.astype(jnp.float32)).max())
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(got, np.float32) / scale,
@@ -114,7 +123,6 @@ def test_flash_matches_model_xla_path():
     (ssd, lambda: [jnp.zeros((1, 128, 1, 64)), jnp.zeros((1, 128, 1)),
                    jnp.zeros((1, 128, 64)), jnp.zeros((1, 128, 64))]),
     (wkv, lambda: [jnp.zeros((1, 16, 1, 64))] * 4 + [jnp.zeros((1, 64))]),
-    (grouped_matmul, lambda: [jnp.zeros((1, 128, 128))] * 2),
 ])
 def test_auto_impl_refuses_non_tpu_backend(op, args):
     """impl="auto" is Pallas on a TPU; elsewhere it raises rather than

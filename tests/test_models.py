@@ -67,9 +67,8 @@ def _full_logits(cfg, params, batch):
                                   "internvl2-2b", "granite-moe-1b-a400m"])
 def test_decode_matches_teacher_forcing(arch):
     """prefill+decode logits == full forward at the same positions
-    (drop-free MoE regime; catches cache/rope/state bugs)."""
-    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32",
-                              moe_capacity_factor=8.0)
+    (catches cache/rope/state bugs)."""
+    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
     params = init_params(cfg, jax.random.key(1))
     B, S, EXTRA = 2, 24, 4
     toks = jax.random.randint(jax.random.key(2), (B, S + EXTRA), 0,
@@ -105,8 +104,7 @@ def test_sliding_window_ring_buffer(step):
     through the jitted step that takes its cache (donated) and writes the
     new token into it in place."""
     cfg = dataclasses.replace(get_smoke_config("mixtral-8x7b"),
-                              dtype="float32", sliding_window=16,
-                              moe_capacity_factor=8.0)
+                              dtype="float32", sliding_window=16)
     params = init_params(cfg, jax.random.key(3))
     B, S, EXTRA = 1, 24, 12   # wraps a window of 16
     toks = jax.random.randint(jax.random.key(4), (B, S + EXTRA), 0,

@@ -1,16 +1,16 @@
 """The dropless MoE and Granite's multipliers against plain references, on
 the CPU at the smoke size with seeded random weights.
 
-* ``moe_mlp(dropless=True)`` against every expert run densely and summed
-  with the renormalised top-k gates, under uniform and under skewed
-  routing (where the capacity path drops rows);
+* ``moe_mlp`` against every expert run densely and summed with the
+  renormalised top-k gates, under uniform and under skewed routing, at 8
+  experts top-2 and at granite's 32 experts top-8;
 * ``ragged_matmul``'s two implementations against each other, empty groups
   and rows padded to the kernel's tile included;
 * the granite smoke model, prefill and then 8 decode steps through the
   cache, against the benchmark's plain reference (``bench/configs/
   decoder.py``) on the same weights; the comparison fails with any one
-  multiplier left out or with the capacity path in the dropless path's
-  place;
+  multiplier left out or with one expert a token fewer than the reference
+  routes to;
 * the serving engine's MoE counters after a ``generate`` with parked
   streams.
 """
@@ -49,9 +49,9 @@ def _dense_moe(p, x, top_k):
     return jnp.einsum("ebsd,bse->bsd", outs, gates)
 
 
-def _moe_inputs(skewed, seed=0, B=2, S=64):
+def _moe_inputs(skewed, seed=0, B=2, S=64, n_experts=E):
     kp, kx = jax.random.split(jax.random.key(seed))
-    p = moe_params(kp, D, F, E, jnp.float32)
+    p = moe_params(kp, D, F, n_experts, jnp.float32)
     x = jax.random.normal(kx, (B, S, D))
     if skewed:
         # a shared direction in every token that the router maps to expert
@@ -62,34 +62,33 @@ def _moe_inputs(skewed, seed=0, B=2, S=64):
     return p, x
 
 
+# (experts, top-k): a small router, and granite-moe-1b-a400m's published one
+ROUTINGS = pytest.mark.parametrize("n_experts,top_k", [(E, K), (32, 8)],
+                                   ids=["8x2", "granite_32x8"])
+
+
+@ROUTINGS
 @pytest.mark.parametrize("skewed", [False, True], ids=["uniform", "skewed"])
-def test_dropless_equals_dense_gated_sum(skewed):
-    p, x = _moe_inputs(skewed)
-    want = _dense_moe(p, x, K)
-    got, aux = moe_mlp(p, x, n_experts=E, top_k=K, dropless=True)
+def test_dropless_equals_dense_gated_sum(skewed, n_experts, top_k):
+    p, x = _moe_inputs(skewed, n_experts=n_experts)
+    want = _dense_moe(p, x, top_k)
+    got, _ = moe_mlp(p, x, n_experts=n_experts, top_k=top_k)
     # float32 both ways: only the order of the sums differs
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
-    capped, caux = moe_mlp(p, x, n_experts=E, top_k=K)
-    if skewed:
-        # expert 0 takes far more rows than its capacity, and what it
-        # drops is missing from the capacity path's output
-        assert float(caux["moe_drop_frac"]) > 0.2
-        assert float(jnp.abs(capped - want).max()) > 0.1
-    assert int(aux["moe_rows"]) == int(caux["moe_rows"]) + round(
-        float(caux["moe_drop_frac"]) * x.shape[0] * x.shape[1] * K)
 
 
+@ROUTINGS
 @pytest.mark.parametrize("skewed", [False, True], ids=["uniform", "skewed"])
-def test_dropless_counts_every_row(skewed):
-    p, x = _moe_inputs(skewed, seed=3)
+def test_dropless_counts_every_row(skewed, n_experts, top_k):
+    p, x = _moe_inputs(skewed, seed=3, n_experts=n_experts)
     B, S, _ = x.shape
-    _, aux = moe_mlp(p, x, n_experts=E, top_k=K, dropless=True)
-    assert float(aux["moe_drop_frac"]) == 0.0
-    assert int(aux["moe_rows"]) == B * S * K
+    _, aux = moe_mlp(p, x, n_experts=n_experts, top_k=top_k)
+    assert int(aux["moe_rows"]) == B * S * top_k
     probs = jax.nn.softmax(x @ p["router"], axis=-1)
-    idx = np.asarray(jax.lax.top_k(probs, K)[1]).ravel()
-    assert int(aux["moe_max_group"]) == np.bincount(idx, minlength=E).max()
+    idx = np.asarray(jax.lax.top_k(probs, top_k)[1]).ravel()
+    assert int(aux["moe_max_group"]) == np.bincount(
+        idx, minlength=n_experts).max()
     if skewed:
         assert int(aux["moe_max_group"]) >= B * S * 0.9
 
@@ -108,19 +107,12 @@ def test_dropless_rows_are_the_groups_the_matmuls_take(monkeypatch):
         return vals, idx.at[0, 0, 0].set(E)
 
     monkeypatch.setattr(jax.lax, "top_k", one_row_astray)
-    got, aux = moe_mlp(p, x, n_experts=E, top_k=K, dropless=True)
+    got, aux = moe_mlp(p, x, n_experts=E, top_k=K)
     assert int(aux["moe_rows"]) == B * S * K - 1
     # the astray row's gated output is gone from its token alone
     assert float(jnp.abs(got[0, 0] - want[0, 0]).max()) > 1e-3
     np.testing.assert_allclose(np.asarray(got)[:, 1:], np.asarray(want)[:, 1:],
                                atol=1e-5, rtol=1e-5)
-
-
-def test_dropless_refuses_gcr_admission():
-    p, x = _moe_inputs(False)
-    with pytest.raises(ValueError, match="dropless"):
-        moe_mlp(p, x, n_experts=E, top_k=K, dropless=True,
-                gcr_admission=True, priority_offset=jnp.int32(0))
 
 
 @pytest.mark.parametrize("sizes", [
@@ -192,7 +184,8 @@ P, G = 32, 8     # prompt, then 8 decode steps through the cache
 # program: only the order of the sums differs (observed 3.4e-7); bfloat16
 # program, as served: matmul operands and the residual stream rounded to 8
 # bits of mantissa (observed 0.6-0.8%).  Faults move it by 49% (attention
-# multiplier), 52% (capacity path), 112-500% (the other multipliers).
+# multiplier), 45% (one expert a token short), 112-500% (the other
+# multipliers).
 TOL = {"float32": 1e-5, "bfloat16": 2e-2}
 
 
@@ -233,11 +226,10 @@ def test_granite_prefill_and_decode_match_reference(dtype, seed):
 @pytest.mark.parametrize("fault", [
     dict(embedding_multiplier=None), dict(attention_multiplier=None),
     dict(residual_multiplier=None), dict(logits_scaling=None),
-    # the capacity path at a capacity the smoke prompt overflows, as a
-    # full-width prefill overflows the default one
-    dict(moe_dropless=False, moe_capacity_factor=0.5),
+    # top-1 routing where the smoke config and the reference route to 2
+    dict(n_experts_active=1),
 ], ids=["no_embedding_multiplier", "no_attention_multiplier",
-        "no_residual_multiplier", "no_logits_scaling", "capacity_path"])
+        "no_residual_multiplier", "no_logits_scaling", "top_k_one_short"])
 def test_granite_reference_check_catches(fault):
     cfg, c = _granite()
     assert _gap(dataclasses.replace(cfg, **fault), c, "float32") \
