@@ -3,11 +3,15 @@
 The TPU compile tests (tests/test_tpu_compile.py) compile these shapes for
 a described v5e, and ``chip_smoke.py`` runs them on the chip against the
 ``ref.py`` oracles.  ``moe_ragged_gmm`` and ``moe_ragged_gmm_mixtral``
-are the dropless MoE's grouped matmul.
+are the dropless MoE's grouped matmul; ``ring_decode_granite`` and
+``ring_decode_qwen3`` the decode step's attention over the K/V ring at the
+benchmark cells' lanes and rings.
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass
 from typing import Callable, Tuple
 
@@ -21,6 +25,8 @@ from .mamba2_ssd.ops import ssd
 from .mamba2_ssd.ref import ssd_ref
 from .moe_gmm.ops import ragged_matmul
 from .moe_gmm.ref import ragged_ref
+from .ring_decode.ops import ring_decode
+from .ring_decode.ref import ring_decode_ref
 from .rwkv6_wkv.ops import wkv
 from .rwkv6_wkv.ref import wkv_ref
 
@@ -101,6 +107,30 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
         return [_normal(k[0], *shapes[0]), _normal(k[1], *shapes[1], 0.05),
                 sizes]
 
+    # decode attention over one ring of a stack of two, as the benchmark
+    # cells hold it: 1024-token prompts + 128 tokens out in a 1168-slot ring
+    # (1156 rounded to whole tiles), one new token at 15/16 of the ring
+    def ring_draw(key, shapes):
+        k = jax.random.split(key, 5)
+        (R, T), pos = shapes[4][0], shapes[4][0][1] * 15 // 16
+        valid = jnp.broadcast_to(jnp.arange(T) <= pos, (R, T))
+        return [_normal(k[0], *shapes[0]), _normal(k[1], *shapes[1]),
+                _normal(k[2], *shapes[2]), jnp.int32(1),
+                valid.astype(jnp.int32), _normal(k[3], *shapes[5]),
+                _normal(k[4], *shapes[6]), jnp.asarray([pos], jnp.int32)]
+
+    def ring_case(name, cfg, lanes):
+        R, C, T = cfg.n_heads, cfg.n_kv_heads * cfg.head_dim, 1168
+        shapes = (((lanes, R, C), "bfloat16"),
+                  ((2, lanes, T, C), "bfloat16"), ((2, lanes, T, C), "bfloat16"),
+                  ((), "int32"), ((R, T), "int32"),
+                  ((lanes, 1, C), "bfloat16"), ((lanes, 1, C), "bfloat16"),
+                  ((1,), "int32"))
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+        return KernelCase(name, functools.partial(ring_decode, scale=scale),
+                          functools.partial(ring_decode_ref, scale=scale),
+                          shapes, ring_draw)
+
     # each op and oracle at its defaults: causal attention, SSD chunk 128,
     # WKV chunk 16
     return (
@@ -113,4 +143,6 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
                    ragged_shapes, ragged_draw),
         KernelCase("moe_ragged_gmm_mixtral", ragged_matmul, ragged_ref,
                    mixtral_shapes, ragged_draw),
+        ring_case("ring_decode_granite", granite, 32),
+        ring_case("ring_decode_qwen3", qwen, 16),
     )

@@ -11,7 +11,8 @@ Conventions:
   32k-prefill cells compile without materializing S x S score matrices.  The
   Pallas kernel in ``repro.kernels.flash_attention`` is the TPU-optimized
   version of the same dataflow.
-* KV caches are ring buffers: full-attention archs size them at max_len,
+* KV caches are ring buffers, lane-dense (B, T, n_kv*d_head) (see
+  ``multihead_attention``): full-attention archs size them at max_len,
   sliding-window archs at the window, which is what makes mixtral's
   long_500k decode cell feasible.
 """
@@ -25,6 +26,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..kernels.ring_decode.ops import ring_decode
 
 # Chunk sizes for the chunked-attention scan (tuned for VMEM-sized tiles).
 Q_CHUNK = 512
@@ -133,21 +136,43 @@ def _plain_attention(q, k, v, q_pos, k_pos, window: int,
     return jnp.einsum("bhst,bthd->bshd", probs, v)
 
 
-def _grouped_decode_attention(q, k, v, q_pos, k_pos, window: int):
-    """Decode attention without KV repetition (cache stays kv-width).
+def _grouped_decode_attention(q, ring_k, ring_v, index, new_k, new_v,
+                              cache_pos, window: int, causal: bool = True):
+    """Decode attention over a stacked, lane-dense K/V ring, without KV
+    repetition.
 
-    q: (B,S,Hkv,G,D); k,v: (B,T,Hkv,D).  The cache's T dim is sharded over
-    the mesh (see ShardingRules.cache_specs); XLA turns the softmax
-    reductions into the distributed flash-decode combine."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    scores = jnp.einsum("bshgd,bthd->bhgst", q, k).astype(jnp.float32) * scale
-    mask = k_pos[None, :] <= q_pos[:, None]
-    if window:
-        mask &= (q_pos[:, None] - k_pos[None, :]) < window
-    mask &= (k_pos >= 0)[None, :]
-    scores = jnp.where(mask[None, None, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhgst,bthd->bshgd", probs, v)
+    q: (B,S,Hkv,G,D); ring_k, ring_v: (N,B,T,Hkv*D), of which ring ``index``
+    is read, each KV head's D lanes side by side in the minor dimension
+    (``init_cache``); new_k, new_v: (B,S,Hkv*D), the step's tokens, which
+    the ring reads as if written into their slots (None: a read-only cache,
+    cross-attention).  q is placed block-diagonally over the Hkv*D lanes
+    (head h's D lanes hold its queries, the others zeros), so one contraction
+    over all the lanes gives every head's scores with no relayout of the
+    ring; the extra products are exact zeros, and each head keeps its own D
+    lanes of the value contraction.  ``ring_decode`` reads the ring from the
+    stack by index (a Pallas kernel on a TPU)."""
+    B, S, Hkv, G, D = q.shape
+    T = ring_k.shape[2]
+    eye = jnp.eye(Hkv, dtype=q.dtype)[:, None, :, None]    # (h, 1, h', 1)
+    qb = (q[..., None, :] * eye).reshape(B, S * Hkv * G, Hkv * D)
+    if causal:
+        k_pos = _cache_slot_positions(T, cache_pos, S)
+        q_pos = cache_pos + jnp.arange(S)
+        valid = (k_pos[None, :] <= q_pos[:, None]) & (k_pos >= 0)[None, :]
+        if window:
+            valid &= (q_pos[:, None] - k_pos[None, :]) < window
+    else:
+        valid = jnp.ones((S, T), bool)
+    valid = jnp.repeat(valid, Hkv * G, axis=0)             # rows (s, h, g)
+    slots = None
+    if new_k is not None:
+        news = _ring_slots(T, cache_pos, S)
+        js = [j for j, _ in news]
+        slots = jnp.stack([jnp.asarray(slot, jnp.int32) for _, slot in news])
+        new_k, new_v = new_k[:, js], new_v[:, js]
+    out = ring_decode(qb, ring_k, ring_v, index, valid, new_k, new_v, slots,
+                      scale=1.0 / math.sqrt(D))
+    return (out.reshape(B, S, Hkv, G, Hkv, D) * eye).sum(axis=4)
 
 
 def _chunk_mask(qp_blk, kp_blk, window: int, causal: bool):
@@ -326,14 +351,18 @@ def flash_attention(q, k, v, q_pos, k_pos, window: int = 0,
 
 def _cache_write(cache: Dict, k: jnp.ndarray, v: jnp.ndarray,
                  cache_pos) -> Dict:
-    """Write the last min(S, Tc) tokens of k/v into the ring buffer."""
+    """Write the last min(S, Tc) tokens of k/v (B,S,kv,dh) into the ring
+    buffers (B,Tc,kv*dh)."""
     Tc = cache["k"].shape[1]
-    S = k.shape[1]
+    B, S = k.shape[:2]
     Lw = min(S, Tc)
     slots = (cache_pos + S - Lw + jnp.arange(Lw)) % Tc
-    ck = cache["k"].at[:, slots].set(k[:, -Lw:].astype(cache["k"].dtype))
-    cv = cache["v"].at[:, slots].set(v[:, -Lw:].astype(cache["v"].dtype))
-    return {"k": ck, "v": cv}
+
+    def write(ring, new):
+        new = new[:, -Lw:].reshape(B, Lw, -1).astype(ring.dtype)
+        return ring.at[:, slots].set(new)
+
+    return {"k": write(cache["k"], k), "v": write(cache["v"], v)}
 
 
 def _ring_slots(Tc: int, cache_pos, S: int):
@@ -342,24 +371,14 @@ def _ring_slots(Tc: int, cache_pos, S: int):
     return [(j, (cache_pos + j) % Tc) for j in range(S - min(S, Tc), S)]
 
 
-def _ring_view(ring: jnp.ndarray, new: jnp.ndarray, cache_pos) -> jnp.ndarray:
-    """The ring (B,Tc,...) as ``_cache_write`` would leave it after writing
-    ``new`` (B,S,...), without writing it: a select per new token, which
-    XLA fuses into the reads of the ring."""
-    iota = jnp.arange(ring.shape[1]).reshape((1, -1) + (1,) * (ring.ndim - 2))
-    for j, slot in _ring_slots(ring.shape[1], cache_pos, new.shape[1]):
-        ring = jnp.where(iota == slot, new[:, j:j + 1].astype(ring.dtype),
-                         ring)
-    return ring
-
-
 def ring_update(buf: jnp.ndarray, new: jnp.ndarray, cache_pos,
-                axis: int) -> jnp.ndarray:
+                axis: int, first=0) -> jnp.ndarray:
     """Write the tokens of ``new`` into the ring buffer ``buf`` (its ring
-    along ``axis``, ``new``'s tokens along the same axis) as
-    ``_cache_write`` does, one ``dynamic_update_slice`` per token: on a
-    donated ``buf`` XLA writes in place, touching the new tokens only."""
-    start = [0] * buf.ndim
+    along ``axis``, ``new``'s tokens along the same axis, its leading axis
+    placed from ``first`` on) as ``_cache_write`` does, one
+    ``dynamic_update_slice`` per token: on a donated ``buf`` XLA writes in
+    place, touching the new tokens only."""
+    start = [first] + [0] * (buf.ndim - 1)
     for j, slot in _ring_slots(buf.shape[axis], cache_pos, new.shape[axis]):
         start[axis] = slot
         tok = jax.lax.slice_in_dim(new, j, j + 1, axis=axis)
@@ -382,7 +401,7 @@ def multihead_attention(
     x: jnp.ndarray,                 # (B, S, d_model)
     positions: jnp.ndarray,         # (S,) absolute positions of x
     kv_src: Optional[jnp.ndarray],  # cross-attn source or None (self)
-    cache: Optional[Dict],          # {"k","v"} ring buffers or None
+    cache: Optional[Dict],          # {"k","v"} rings (see Modes) or None
     cache_pos,                      # scalar: tokens already in cache
     *,
     n_heads: int,
@@ -399,8 +418,17 @@ def multihead_attention(
     sc=lambda x, kind=None: x,      # sharding-constraint hook
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Returns (output (B,S,d_model), cache out): the updated cache, or in
-    self-attention decode the new tokens' {"k","v"} (B,S,n_kv,d_head), which
-    the caller writes into the ring (``ring_update``).
+    self-attention decode the new tokens' {"k","v"} (B,S,n_kv*d_head), which
+    the caller writes into the ring (``ring_update``), and in
+    cross-attention decode None (that cache is read only).
+
+    A cache holds K and V lane-dense, (B, T, n_kv*d_head): the KV heads and
+    the head dim merged into the minor dimension, which is a multiple of the
+    TPU's 128 lanes in every configuration.  One position is then a
+    contiguous [B, n_kv*d_head] slab of whole tiles, so decode writes a token
+    without touching the rest of the ring.  (With a head dim of 64 in the
+    minor place XLA would lay the ring out T-minor and pad T to the lanes,
+    and a token's write would stride across the whole stack.)
 
     Every path scales scores by 1/sqrt(d_head); a ``q_scale`` of
     ``m * sqrt(d_head)`` makes that a score scale of ``m``.
@@ -409,9 +437,11 @@ def multihead_attention(
       train   - cache is None: attend within the sequence.
       prefill - cache given, decode=False: attend within the sequence,
                 write the last min(S, cache_len) tokens into the ring.
-      decode  - cache given, decode=True: attend over the whole ring
-                buffer as it reads with the current token(s) written, and
-                return those tokens' k/v, not a new ring.
+      decode  - cache given, decode=True: the stacked rings
+                {"k","v"} (N,B,T,n_kv*d_head) and "index", the ring to
+                read (a layer's); attend over the whole ring as it reads
+                with the current token(s) written, and return those
+                tokens' k/v, not a new ring.
     """
     B, S, _ = x.shape
     G = n_heads // n_kv
@@ -425,11 +455,7 @@ def multihead_attention(
 
     if is_cross and decode:
         # cross-attention decode: k/v live in the (static) cross cache
-        k, v = cache["k"], cache["v"]
-        new_cache = cache
-        k_pos = jnp.arange(k.shape[1])
-        q_pos = positions
-        k_full, v_full = k, v
+        k = v = new_cache = None
     else:
         src = x if kv_src is None else kv_src
         Tsrc = src.shape[1]
@@ -449,14 +475,12 @@ def multihead_attention(
         if q_scale is not None:
             q = q * jnp.asarray(q_scale, q.dtype)
 
-        if cache is not None and decode:
-            # the ring is read with the new tokens selected into their
-            # slots, never written here: the caller writes them
+        if decode:
+            # the ring is read with the new tokens in their slots, never
+            # written here: the caller writes them
+            k = k.reshape(B, S, n_kv * d_head)
+            v = v.reshape(B, S, n_kv * d_head)
             new_cache = {"k": k, "v": v}
-            k_full = _ring_view(cache["k"], k, cache_pos)
-            v_full = _ring_view(cache["v"], v, cache_pos)
-            k_pos = _cache_slot_positions(cache["k"].shape[1], cache_pos, S)
-            q_pos = cache_pos + jnp.arange(S)
         elif cache is not None:
             new_cache = _cache_write(cache, k, v, cache_pos)
             # prefill: attend within the sequence
@@ -470,15 +494,9 @@ def multihead_attention(
             q_pos = positions
 
     if decode:
-        if not causal:
-            scale = 1.0 / math.sqrt(d_head)
-            scores = jnp.einsum("bshgd,bthd->bhgst", q, k_full
-                                ).astype(jnp.float32) * scale
-            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-            out = jnp.einsum("bhgst,bthd->bshgd", probs, v_full)
-        else:
-            out = _grouped_decode_attention(q, k_full, v_full, q_pos, k_pos,
-                                            window)
+        out = _grouped_decode_attention(q, cache["k"], cache["v"],
+                                        cache["index"], k, v, cache_pos,
+                                        window, causal)
         out = out.reshape(B, S, n_heads * d_head) @ p["wo"]
         return out, new_cache
 

@@ -39,6 +39,8 @@ from . import rwkv6 as R
 
 Params = Dict[str, Any]
 _id_sc = lambda x, kind=None: x
+# ring lengths are a multiple of a 16-bit value's sublane tile on a TPU
+RING_ALIGN = 16
 
 
 # ---------------------------------------------------------------------------
@@ -143,8 +145,14 @@ def param_shapes(cfg: ModelConfig):
 
 
 def _attn_cache(cfg: ModelConfig, B: int, max_len: int, dtype) -> Dict:
+    """K/V ring buffers, lane-dense (B, Tc, kv*dh): see
+    ``layers.multihead_attention``.  Tc holds max_len tokens, or the window,
+    rounded up to whole sublane tiles (``RING_ALIGN``): slots never written
+    are masked, and the TPU then lays the ring out row-major with no
+    padding, as the decode kernel reads it."""
     Tc = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
-    shape = (B, Tc, cfg.n_kv_heads, cfg.head_dim)
+    Tc = -(-Tc // RING_ALIGN) * RING_ALIGN
+    shape = (B, Tc, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -187,7 +195,7 @@ def init_cache(cfg: ModelConfig, B: int, max_len: int,
         cache["shared"] = jax.tree.map(
             lambda a: jnp.broadcast_to(a[None], (n_inv,) + a.shape), one)
     if cfg.is_encdec:
-        shape = (Ld, B, enc_len, cfg.n_kv_heads, cfg.head_dim)
+        shape = (Ld, B, enc_len, cfg.n_kv_heads * cfg.head_dim)
         cache["cross"] = {"k": jnp.zeros(shape, dtype),
                           "v": jnp.zeros(shape, dtype)}
     return cache
@@ -305,15 +313,27 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
            cache_pos, *, decode: bool, cross_src, sc, remat: bool):
     """Run the decoder stack.  caches: stacked per-layer cache or None.
 
-    In decode an attention layer's ring is read-only in the scan: each layer
-    hands back its new tokens' k/v, written into the stacked ring after the
-    scan, so a step over a donated cache writes those tokens and copies no
-    cache.  mamba2/rwkv6 states are rewritten whole every step."""
+    In decode the K/V rings are read-only in the scan and not among its
+    xs: each layer reads its ring from the stack by index (see
+    ``layers.multihead_attention``) and hands back its new tokens' k/v,
+    written into the stacked ring after the scan, so a step over a donated
+    cache writes those tokens and copies no cache, nor any layer's ring.
+    mamba2/rwkv6 states are rewritten whole every step."""
     kind = cfg.block_pattern[0]
+    rings = decode and kind in ("attn", "moe")
+    layer_caches = caches["layers"] if caches is not None else None
+    cross_caches = caches.get("cross") if (caches is not None
+                                           and cfg.is_encdec) else None
+    shared0 = caches.get("shared") if (caches is not None
+                                       and cfg.shared_attn_every) else None
 
     def unit(carry, xs):
         x, shared_cache = carry
         lp, lcache, idx, lcross = xs
+        if rings:
+            lcache = dict(layer_caches, index=idx)
+        if decode and cross_caches is not None:
+            lcross = dict(cross_caches, index=idx)
         aux = {}
         if kind in ("attn", "moe"):
             x, new_lcache, new_lcross, aux = _apply_attn_block(
@@ -337,18 +357,22 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
             def with_shared(operands):
                 x, shared_cache = operands
                 sp = params["shared_attn"]
-                scache = (None if shared_cache is None else
-                          jax.tree.map(lambda a: a[inv], shared_cache))
+                if shared_cache is None:
+                    scache = None
+                elif decode:
+                    scache = dict(shared_cache, index=inv)
+                else:
+                    scache = jax.tree.map(lambda a: a[inv], shared_cache)
                 x2, new_scache, _, _ = _apply_attn_block(
                     cfg, sp, x, positions, scache, cache_pos,
                     decode=decode, causal=True, cross_src=None,
                     cross_cache=None, sc=sc)
                 if decode:      # the block returned the new tokens' k/v
-                    new_scache = jax.tree.map(
-                        lambda buf, new: L.ring_update(buf, new, cache_pos,
-                                                       axis=1),
-                        scache, new_scache)
-                if shared_cache is not None:
+                    shared_cache = jax.tree.map(
+                        lambda buf, new: L.ring_update(
+                            buf, new[None], cache_pos, axis=2, first=inv),
+                        shared_cache, new_scache)
+                elif shared_cache is not None:
                     shared_cache = jax.tree.map(
                         lambda buf, upd: buf.at[inv].set(upd),
                         shared_cache, new_scache)
@@ -366,26 +390,21 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
     unit_fn = jax.checkpoint(unit) if remat else unit
 
     idxs = jnp.arange(cfg.n_layers)
-    layer_caches = caches["layers"] if caches is not None else None
-    cross_caches = caches.get("cross") if (caches is not None
-                                           and cfg.is_encdec) else None
-    shared0 = caches.get("shared") if (caches is not None
-                                       and cfg.shared_attn_every) else None
-
-    xs = (params["layers"], layer_caches, idxs, cross_caches)
+    xs = (params["layers"], None if rings else layer_caches, idxs,
+          None if decode else cross_caches)
     (x, shared_out), (new_layer_caches, new_cross, aux) = jax.lax.scan(
         unit_fn, (x, shared0), xs)
 
     new_caches = None
     if caches is not None:
-        if decode and kind in ("attn", "moe"):
-            # ys holds the new tokens (L, B, S, kv, dh): the ring's T is axis 2
+        if rings:
+            # ys holds the new tokens (L, B, S, kv*dh): the ring's T is axis 2
             new_layer_caches = jax.tree.map(
                 lambda buf, new: L.ring_update(buf, new, cache_pos, axis=2),
                 layer_caches, new_layer_caches)
         new_caches = dict(caches)
         new_caches["layers"] = new_layer_caches
-        if cfg.is_encdec:
+        if cfg.is_encdec and not decode:
             new_caches["cross"] = new_cross
         if cfg.shared_attn_every:
             new_caches["shared"] = shared_out

@@ -13,8 +13,9 @@ Scheme (DESIGN.md section 5):
 * **SP**: the train/prefill residual stream is sharded (dp, model, None) on
   (B, S, D) - Megatron-style sequence parallelism; XLA inserts the
   all-gather/reduce-scatter pairs around attention/MLP.
-* **Decode**: batch on ``data`` when divisible; KV caches sharded along the
-  *sequence* dim on ``model`` (and on ``data`` too for batch=1 long-context)
+* **Decode**: batch on ``data`` when divisible; KV caches, lane-dense
+  (L, B, T, kv*dh), sharded along the *sequence* dim T on ``model`` (and
+  on ``data`` too for batch=1 long-context)
   - a GSPMD-native distributed flash-decode; SSM/WKV states shard heads on
   ``model``.
 * **ZeRO-1**: optimizer moments additionally shard their largest replicated
@@ -225,7 +226,14 @@ class ShardingRules:
 
     # -- caches -----------------------------------------------------------------
     def cache_specs(self, cache_tree, batch: int) -> Any:
-        """Decode-cache sharding.  Leaves are (L, B, ...) stacked buffers."""
+        """Decode-cache sharding.  Leaves are (L, B, ...) stacked buffers.
+
+        K/V rings (self, zamba2's shared block, whisper's cross cache) are
+        (L, B, T, kv*dh): heads and head dim merged into the minor dim, so
+        it fills the TPU's 128-lane tiles and one position is a contiguous
+        slab (decode writes a token without striding across the ring).
+        Batch goes on dp, the sequence axis T on model (and dp at batch 1).
+        """
         b_axis = self._batch_axis(batch)
 
         def f(path, leaf):
@@ -235,8 +243,8 @@ class ShardingRules:
                 return P()
             name = keys[-1]
             entries: list = [None] * nd
-            if name in ("k", "v") and nd == 5:
-                # (L, B, T, kv, dh): batch on dp; seq on model (+dp if b=1)
+            if name in ("k", "v") and nd == 4:
+                # (L, B, T, kv*dh): batch on dp; seq on model (+dp if b=1)
                 entries[1] = b_axis
                 seq_axes = (self.tp if b_axis is not None
                             else (tuple(self.dp) + (self.tp,)))

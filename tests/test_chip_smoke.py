@@ -63,6 +63,12 @@ _PHASES = textwrap.dedent("""
              "moe_ragged_gmm_mixtral": (((160, 512), "bfloat16"),
                                         ((4, 512, 256), "bfloat16"),
                                         ((4,), "int32"))}
+    for name in ("ring_decode_granite", "ring_decode_qwen3"):
+        small[name] = (((2, 4, 128), "bfloat16"),
+                       ((2, 2, 64, 128), "bfloat16"),
+                       ((2, 2, 64, 128), "bfloat16"), ((), "int32"),
+                       ((4, 64), "int32"), ((2, 1, 128), "bfloat16"),
+                       ((2, 1, 128), "bfloat16"), ((1,), "int32"))
     cases = [dataclasses.replace(c, shapes=small[c.name])
              for c in real_width_cases()]
     chip_smoke.check_kernels(clock, cases, impl="interpret")

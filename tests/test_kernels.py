@@ -11,6 +11,9 @@ from repro.kernels.mamba2_ssd.ops import ssd
 from repro.kernels.mamba2_ssd.ref import ssd_ref
 from repro.kernels.moe_gmm.ops import ragged_matmul
 from repro.kernels.moe_gmm.ref import ragged_ref
+from repro.kernels.ring_decode.kernel import block_slots
+from repro.kernels.ring_decode.ops import ring_decode
+from repro.kernels.ring_decode.ref import ring_decode_ref
 from repro.kernels.rwkv6_wkv.ops import wkv
 from repro.kernels.rwkv6_wkv.ref import wkv_ref
 
@@ -101,6 +104,46 @@ def test_ragged_gmm_sweep(sizes, K, N, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32) / scale,
                                np.asarray(want, np.float32) / scale,
                                atol=tol)
+
+
+@pytest.mark.parametrize("N,B,T,C,R,n", [
+    (3, 2, 40, 64, 8, 1),       # T in one tile, not a multiple of 128
+    (2, 2, 1168, 512, 16, 1),   # granite's ring and lanes at 2 lanes
+    (2, 1, 4096, 1024, 16, 2),  # four tiles, two new tokens
+    (2, 1, 300, 8192, 8, 1),    # 128-slot tiles, the last past the ring
+    (2, 2, 384, 256, 8, 0),     # a read-only cache (cross-attention)
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ring_decode_sweep(N, B, T, C, R, n, dtype):
+    """The decode kernel (interpreted) against the oracle: ring 1 of a
+    stack read by index, masked slots, and new tokens standing in for
+    their slots, among them the tile's last slot."""
+    ks = jax.random.split(KEY, 6)
+    q = jax.random.normal(ks[0], (B, R, C), dtype)
+    rk = jax.random.normal(ks[1], (N, B, T, C), dtype)
+    rv = jax.random.normal(ks[2], (N, B, T, C), dtype)
+    valid = jax.random.uniform(ks[3], (R, T)) > 0.3
+    new = dict(new_k=jax.random.normal(ks[4], (B, n, C), dtype),
+               new_v=jax.random.normal(ks[5], (B, n, C), dtype),
+               slots=jnp.asarray([5, T - 1][:n], jnp.int32)) if n else {}
+    args = (q, rk, rv, jnp.int32(1), valid)
+    got = ring_decode(*args, **new, scale=0.125, impl="interpret")
+    want = ring_decode_ref(*args, **new, scale=0.125)
+    assert got.shape == (B, R, C) and got.dtype == dtype
+    tol = 2e-2 if dtype == jnp.bfloat16 else 5e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+def test_ring_decode_tiles():
+    """A ring is read whole where a K or V tile of it fits 2.5 MiB, else in
+    tiles of whole 128-slot lane tiles that fit."""
+    assert block_slots(1168, 512, 2) == 1168       # granite's
+    assert block_slots(1168, 1024, 2) == 1168      # qwen3's
+    assert block_slots(1168, 2560, 2) == 512       # zamba2's
+    assert block_slots(1168, 4096, 2) == 256       # deepseek's
+    assert block_slots(32768, 8192, 2) == 128
 
 
 def test_flash_matches_model_xla_path():

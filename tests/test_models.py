@@ -62,15 +62,11 @@ def _full_logits(cfg, params, batch):
     return lm_logits(cfg, params, hidden)
 
 
-@pytest.mark.parametrize("arch", ["qwen3-0.6b", "zamba2-2.7b", "rwkv6-7b",
-                                  "whisper-base", "mixtral-8x7b",
-                                  "internvl2-2b", "granite-moe-1b-a400m"])
-def test_decode_matches_teacher_forcing(arch):
-    """prefill+decode logits == full forward at the same positions
-    (catches cache/rope/state bugs)."""
-    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
+def _decode_errors(cfg, B=2, S=24, EXTRA=4):
+    """prefill S tokens, decode EXTRA more one at a time: each step's
+    largest logit gap to the full forward at that position, and the last
+    cache."""
     params = init_params(cfg, jax.random.key(1))
-    B, S, EXTRA = 2, 24, 4
     toks = jax.random.randint(jax.random.key(2), (B, S + EXTRA), 0,
                               cfg.vocab_size)
     bf = {"tokens": toks}
@@ -94,6 +90,50 @@ def test_decode_matches_teacher_forcing(arch):
                                     toks[:, S + t][:, None])
         errs.append(np.abs(np.asarray(logits[:, 0], np.float32)
                            - ref[:, off + S + t]).max())
+    return errs, cache
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "zamba2-2.7b", "rwkv6-7b",
+                                  "whisper-base", "mixtral-8x7b",
+                                  "internvl2-2b", "granite-moe-1b-a400m"])
+def test_decode_matches_teacher_forcing(arch):
+    """prefill+decode logits == full forward at the same positions
+    (catches cache/rope/state bugs)."""
+    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
+    errs, _ = _decode_errors(cfg)
+    assert max(errs) < 1e-4, errs
+
+
+@pytest.mark.parametrize("impl", ["auto", "interpret"])
+@pytest.mark.parametrize("arch,over,rings", [
+    # granite's head dim: half a lane tile, the case the layout is for
+    ("granite-moe-1b-a400m", {"d_head": 64}, ("layers",)),
+    # a sliding-window ring the prompt already wraps
+    ("mixtral-8x7b", {"sliding_window": 16}, ("layers",)),
+    ("zamba2-2.7b", {}, ("shared",)),               # the shared block's ring
+    ("whisper-base", {}, ("layers", "cross")),       # and the cross cache
+])
+def test_decode_over_lane_dense_rings(arch, over, rings, impl, monkeypatch):
+    """Every K/V buffer of the cache is lane-dense, (L, B, T, kv*dh), and
+    decode over it gives the cache-free forward's logits, through the
+    oracle a CPU takes (``auto``) and through the TPU's Pallas kernel
+    (interpreted): its reading of the stacked rings by layer index, of the
+    new tokens and of the masks is the model's."""
+    import functools
+
+    from repro.kernels.ring_decode import ops
+    from repro.models import layers
+
+    monkeypatch.setattr(layers, "ring_decode",
+                        functools.partial(ops.ring_decode, impl=impl))
+    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32", **over)
+    errs, cache = _decode_errors(cfg, EXTRA=8)
+    for name in rings:
+        for buf in (cache[name]["k"], cache[name]["v"]):
+            assert buf.ndim == 4, (name, buf.shape)
+            assert buf.shape[3] == cfg.n_kv_heads * cfg.head_dim
+    if cfg.sliding_window:
+        assert cache["layers"]["k"].shape[2] == cfg.sliding_window
     assert max(errs) < 1e-4, errs
 
 

@@ -12,7 +12,9 @@ import sys
 import textwrap
 from pathlib import Path
 
+import jax
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro.config import SHAPES
 from repro.configs import ARCHS, get_config
@@ -84,6 +86,17 @@ def test_cache_specs_divisible(arch):
 
     __import__("jax").tree_util.tree_map_with_path(
         lambda p, l, s: check(p, l, s), caches, specs)
+
+    # every K/V ring is lane-dense (L, B, T, kv*dh) with T, axis 2, on the
+    # model axis; nothing else of it is split
+    flat = jax.tree_util.tree_flatten_with_path(caches)[0]
+    spec_leaves = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
+    rings = [(path, leaf, spec) for (path, leaf), spec
+             in zip(flat, spec_leaves) if path[-1].key in ("k", "v")]
+    assert rings or cfg.block_pattern[0] == "rwkv6"
+    for path, leaf, spec in rings:
+        assert leaf.shape[3] == cfg.n_kv_heads * cfg.head_dim, path
+        assert spec[2] is not None and spec[3] is None, (path, spec)
 
 
 _SUBPROCESS_TRAIN = textwrap.dedent("""

@@ -47,3 +47,53 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("arch,slots", [("qwen3-0.6b", 16),
+                                        ("granite-moe-1b-a400m", 32)])
+def test_decode_cache_is_lane_dense_for_v5e(arch, slots, one_chip,
+                                            monkeypatch):
+    """``JaxServeEngine``'s decode program at full width, at the benchmark
+    cells' slots and ring (1024-token prompts, 128 tokens out), reads and
+    writes its K/V ring in place with no lane padding: the aliased bytes
+    are the buffers' logical bytes and ``pos``'s one 512-byte tile.  A head
+    dim of 64 (granite) in the minor place would be laid out T-minor and
+    padded to the lanes; a ring the TPU lays out otherwise than the decode
+    kernel reads it would be copied whole.  Its temporaries hold less than
+    one layer's K ring: no layer's ring is copied out of the stack."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.kernels.moe_gmm import ops as gmm
+    from repro.kernels.ring_decode import ops as ring
+    from repro.models import init_params, layers, moe
+    from repro.models.transformer import RING_ALIGN
+    from repro.serving.engine import JaxServeEngine
+
+    # the TPU's path: the backend seen while tracing here is the CPU
+    monkeypatch.setattr(moe, "ragged_matmul",
+                        functools.partial(gmm.ragged_matmul, impl="pallas"))
+    monkeypatch.setattr(layers, "ring_decode",
+                        functools.partial(ring.ring_decode, impl="pallas"))
+    cfg = get_config(arch)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    max_len = 1024 + 128 + 4
+    eng = JaxServeEngine(cfg, params, n_slots=slots, max_len=max_len)
+    batch = {"tokens": jax.ShapeDtypeStruct((slots, 1024), jnp.int32)}
+    tok, cache = jax.eval_shape(eng.prefill_fn, params, batch)[:2]
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    compiled = eng.decode_fn.lower(on_chip(params), on_chip(cache),
+                                   on_chip(tok)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    k, v = cache["layers"]["k"], cache["layers"]["v"]
+    ring = -(-max_len // RING_ALIGN) * RING_ALIGN
+    assert k.shape == (cfg.n_layers, slots, ring,
+                       cfg.n_kv_heads * cfg.head_dim)
+    assert 0 <= mem.alias_size_in_bytes - nbytes(k) - nbytes(v) <= 512
+    assert mem.temp_size_in_bytes < nbytes(k) // cfg.n_layers
