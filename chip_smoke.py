@@ -3,19 +3,22 @@
     python chip_smoke.py               # one chip
     python chip_smoke.py --four-chips  # sharded training on a 2x2 host only
 
-One chip: serves qwen3-0.6b, then granite-moe-1b-a400m (dropless MoE), at
-their published widths (random weights from a seed) through
-``serve_real_model``, the function ``repro.launch.serve`` runs: twice as
-many streams as slots (16 on 8, 64 on 32), so the streams past the slots
-wait for admission and are served in a second wave, with 1024-token prompts
-and 32 generated tokens.  It checks that every token is in the
-vocabulary, that a second ``generate`` gives the same tokens, that the
-last-position logits of ``prefill`` match the cache-free pass of
-``forward_train`` and that the engine's prefill program serves their
-argmax, for the MoE that the engine's counters show every routed row
-computed, and that the decode program updates its cache in place (every
-step took its input cache; ``memory_analysis()``: the cache aliased to the
-output, no stacked K among the temporaries).  Then it runs each case of
+One chip: serves qwen3-0.6b, then granite-moe-1b-a400m (dropless MoE),
+then granite-4.0-h-small (Mamba2 and attention layers, each with an MoE
+holding 9 of 72 experts and a shared expert), at their published widths
+(random weights from a seed) through ``serve_real_model``, the function
+``repro.launch.serve`` runs: twice as many streams as slots (16 on 8, 64 on
+32, 32 on 16), so the streams past the slots wait for admission and are
+served in a second wave, with 1024-token prompts and 32 generated tokens.
+It checks that every token is in the vocabulary, that a second
+``generate`` gives the same tokens, that the last-position logits of
+``prefill`` match the cache-free pass of ``forward_train`` and that the
+engine's prefill program serves their argmax, for an MoE that the engine's
+counters show every routed row computed here or counted as routed to an
+expert held elsewhere, and that the decode program updates its cache in
+place (every step took its input cache; ``memory_analysis()``: the whole
+cache, K/V rings and SSM and conv states alike, aliased to the output, no
+stacked cache buffer among the temporaries).  Then it runs each case of
 ``repro.kernels.cases`` natively against its ``ref.py`` oracle: the three
 Pallas kernels of ``repro.kernels`` and the MoE's megablox ``gmm`` at
 granite-moe-1b-a400m's and mixtral-8x7b's widths.
@@ -90,6 +93,7 @@ def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
     import jax
     import numpy as np
 
+    from repro.config import MOE_KINDS
     from repro.launch.serve import serve_real_model
     from repro.models import forward_hidden, lm_logits, prefill
 
@@ -106,16 +110,20 @@ def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
         raise AssertionError(f"tokens shape {tokens.shape}")
     if not ((tokens >= 0) & (tokens < cfg.vocab_size)).all():
         raise AssertionError("a generated token is outside the vocabulary")
-    moe_layers = cfg.layer_kinds().count("moe")
+    moe_layers = sum(k in MOE_KINDS for k in cfg.layer_kinds())
     if moe_layers:
-        # every (token, expert) row of every layer, prefill and decode
+        # every (token, expert) row of every layer, prefill and decode:
+        # computed, or routed to an expert held elsewhere
         rows = (streams * (prompt_len + gen_len) * cfg.n_experts_active
                 * moe_layers)
-        print(f"serve: moe_rows={eng.moe_stats.rows} (no drop: {rows}) "
-              f"moe_max_group={eng.moe_stats.max_group}")
-        if eng.moe_stats.rows != rows:
-            raise AssertionError(f"MoE computed {eng.moe_stats.rows} rows "
-                                 f"of {rows}")
+        ms = eng.moe_stats
+        print(f"serve: moe_rows={ms.rows} (no drop: {rows}) "
+              f"moe_rows_elsewhere={ms.rows_elsewhere} "
+              f"moe_max_group={ms.max_group}")
+        if ms.rows + ms.rows_elsewhere != rows or (
+                ms.rows_elsewhere and cfg.n_experts_held == cfg.n_experts):
+            raise AssertionError(f"MoE computed {ms.rows} rows and counted "
+                                 f"{ms.rows_elsewhere} elsewhere of {rows}")
 
     with clock.phase("serve_second_generate"):
         again = eng.generate(prompts, gen_len=gen_len)
@@ -157,9 +165,10 @@ def check_serving(clock, arch, *, smoke, streams, slots, prompt_len,
 def check_decode_in_place(clock, eng, prompts):
     """The decode program takes its cache (donated) and writes it in place:
     every step the engine ran took its input cache, the program aliases the
-    whole cache to its output, and its temporaries hold no stacked K or V.
-    The CPU backend computes bf16 in float32 and keeps converted copies of
-    the stack among its temporaries, so that bound is a TPU's only."""
+    whole cache to its output, and its temporaries hold less than its
+    largest stacked buffer (K or V, or a hybrid's SSM states).  The CPU
+    backend computes bf16 in float32 and keeps converted copies of the
+    stack among its temporaries, so that bound is a TPU's only."""
     import jax
 
     s = eng.stats
@@ -173,11 +182,11 @@ def check_decode_in_place(clock, eng, prompts):
                                   tok).compile().memory_analysis()
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(cache))
-    k = cache["layers"]["k"]
-    k_bytes = k.size * k.dtype.itemsize
+    k_bytes = max(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(cache))
     print(f"serve: decode alias_bytes={mem.alias_size_in_bytes} "
           f"temp_bytes={mem.temp_size_in_bytes} cache_bytes={cache_bytes} "
-          f"stacked_k_bytes={k_bytes} cache_donated={s.cache_donated}")
+          f"stacked_bytes={k_bytes} cache_donated={s.cache_donated}")
     if mem.alias_size_in_bytes < cache_bytes:
         raise AssertionError("the decode program does not take its cache "
                              "in place")
@@ -293,6 +302,8 @@ def main() -> None:
                       prompt_len=1024, gen_len=32)
         check_serving(clock, "granite-moe-1b-a400m", smoke=False, streams=64,
                       slots=32, prompt_len=1024, gen_len=32)
+        check_serving(clock, "granite-4.0-h-small", smoke=False, streams=32,
+                      slots=16, prompt_len=1024, gen_len=32)
         check_kernels(clock, real_width_cases())
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -23,11 +23,13 @@ def pad_to(x: int, m: int) -> int:
 # ---------------------------------------------------------------------------
 
 # Block kinds a layer can be:
-#   "attn"    - GQA attention + dense MLP        (classic transformer)
-#   "moe"     - GQA attention + mixture-of-experts MLP
-#   "mamba2"  - Mamba2 (SSD) block
-#   "rwkv6"   - RWKV6 block (time mix + channel mix)
-BLOCK_KINDS = ("attn", "moe", "mamba2", "rwkv6")
+#   "attn"       - GQA attention + dense MLP        (classic transformer)
+#   "moe"        - GQA attention + mixture-of-experts MLP
+#   "mamba2"     - Mamba2 (SSD) block
+#   "mamba2_moe" - Mamba2 (SSD) mixer + mixture-of-experts MLP
+#   "rwkv6"      - RWKV6 block (time mix + channel mix)
+BLOCK_KINDS = ("attn", "moe", "mamba2", "mamba2_moe", "rwkv6")
+MOE_KINDS = ("moe", "mamba2_moe")
 
 
 @dataclass(frozen=True)
@@ -46,12 +48,17 @@ class ModelConfig:
     d_head: int = 0                # 0 => d_model // n_heads
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rope: bool = True              # False: no position embedding (NoPE)
     sliding_window: int = 0        # 0 = full attention
 
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0             # the router's outputs
     n_experts_active: int = 0
     moe_d_ff: int = 0
+    # experts 0 .. experts_held-1 are held (and computed) here, the chip's
+    # share under expert parallelism; 0 = all n_experts
+    experts_held: int = 0
+    shared_d_ff: int = 0           # a shared SwiGLU expert beside the MoE
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -119,6 +126,21 @@ class ModelConfig:
         pat = self.block_pattern
         return tuple(pat[i % len(pat)] for i in range(self.n_layers))
 
+    def layer_runs(self) -> Tuple[Tuple[str, int, int], ...]:
+        """(kind, first layer, layers) of each run of same-kind layers, in
+        order: one run for a model whose layers are all of one kind."""
+        runs: List[list] = []
+        for i, kind in enumerate(self.layer_kinds()):
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, i, 1])
+        return tuple(tuple(r) for r in runs)
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
     @property
     def d_inner(self) -> int:
         """Mamba2 inner width."""
@@ -149,15 +171,16 @@ class ModelConfig:
                 if kind == "attn":
                     total += 3 * d * self.d_ff
                 else:
-                    total += self.n_experts * 3 * d * self.moe_d_ff \
-                        + d * self.n_experts           # router
-            elif kind == "mamba2":
+                    total += self._moe_params()
+            elif kind in ("mamba2", "mamba2_moe"):
                 di, ns, nh = self.d_inner, self.ssm_state, self.ssm_heads
                 total += d * (2 * di + 2 * ns + nh)    # in_proj (z,x,B,C,dt)
                 total += (di + 2 * ns) * self.ssm_conv  # conv
                 total += 2 * nh + di                   # A_log, D, dt_bias? (nh,nh,di gate norm)
                 total += di * d                        # out_proj
                 total += d                             # block norm
+                if kind == "mamba2_moe":
+                    total += d + self._moe_params()
             elif kind == "rwkv6":
                 total += 6 * d * d                     # r,k,v,w,g,out projections
                 total += 2 * d * self.d_ff             # channel mix (k,v)...
@@ -178,13 +201,19 @@ class ModelConfig:
             total += self.frontend_dim * d
         return total
 
+    def _moe_params(self) -> int:
+        """One MoE layer's held experts, router and shared expert."""
+        return (self.n_experts_held * 3 * self.d_model * self.moe_d_ff
+                + self.d_model * self.n_experts
+                + 3 * self.d_model * self.shared_d_ff)
+
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only active experts)."""
         if self.n_experts == 0:
             return self.param_count()
         total = self.param_count()
-        moe_layers = sum(1 for k in self.layer_kinds() if k == "moe")
-        inactive = self.n_experts - self.n_experts_active
+        moe_layers = sum(1 for k in self.layer_kinds() if k in MOE_KINDS)
+        inactive = max(self.n_experts_held - self.n_experts_active, 0)
         total -= moe_layers * inactive * 3 * self.d_model * self.moe_d_ff
         return total
 

@@ -19,6 +19,7 @@ ARCHS: List[str] = [
     "internvl2-2b",
     "mixtral-8x7b",
     "granite-moe-1b-a400m",
+    "granite-4.0-h-small",
 ]
 
 _MODULES: Dict[str, str] = {a: a.replace("-", "_").replace(".", "_")

@@ -408,7 +408,7 @@ def multihead_attention(
     n_kv: int,
     d_head: int,
     qk_norm: bool = False,
-    rope_theta: float = 1e4,
+    rope_theta: Optional[float] = 1e4,  # None: no RoPE (NoPE attention)
     window: int = 0,
     causal: bool = True,
     decode: bool = False,           # True: attend over the cache (S small)
@@ -467,7 +467,7 @@ def multihead_attention(
             k = rms_norm(k, p["k_norm"], eps)
 
         use_rope = not is_cross  # RoPE only for self-attention
-        if use_rope:
+        if use_rope and rope_theta is not None:
             q = apply_rope(q, positions, rope_theta)
             src_pos = positions if not decode else (
                 cache_pos + jnp.arange(Tsrc))

@@ -77,6 +77,18 @@ def _causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
     return jax.nn.silu(out), new_state
 
 
+def _convs(p: Dict, xin, Bmat, Cmat, state: Dict):
+    """The three depthwise causal convs, from ``state`` ({} : zeros).
+    Returns (x, B, C, new conv state)."""
+    xin, cs_x = _causal_conv(xin, p["conv_x_w"], p["conv_x_b"],
+                             state.get("x"))
+    Bmat, cs_B = _causal_conv(Bmat, p["conv_B_w"], p["conv_B_b"],
+                              state.get("B"))
+    Cmat, cs_C = _causal_conv(Cmat, p["conv_C_w"], p["conv_C_b"],
+                              state.get("C"))
+    return xin, Bmat, Cmat, {"x": cs_x, "B": cs_B, "C": cs_C}
+
+
 def _project(p: Dict, x: jnp.ndarray):
     return (x @ p["w_z"], x @ p["w_x"], x @ p["w_B"], x @ p["w_C"],
             x @ p["w_dt"])
@@ -149,31 +161,30 @@ def mamba2_forward(
 ):
     """Full-sequence forward (train / prefill)."""
     B, S, _ = x.shape
-    z, xin, Bmat, Cmat, dt = _project(p, x)
+    with jax.named_scope("mamba.proj"):
+        z, xin, Bmat, Cmat, dt = _project(p, x)
 
-    cs = conv_state if conv_state is not None else {}
-    xin, cs_x = _causal_conv(xin, p["conv_x_w"], p["conv_x_b"],
-                             cs.get("x"))
-    Bmat, cs_B = _causal_conv(Bmat, p["conv_B_w"], p["conv_B_b"],
-                              cs.get("B"))
-    Cmat, cs_C = _causal_conv(Cmat, p["conv_C_w"], p["conv_C_b"],
-                              cs.get("C"))
-    new_conv_state = {"x": cs_x, "B": cs_B, "C": cs_C}
+    with jax.named_scope("mamba.conv"):
+        xin, Bmat, Cmat, new_conv_state = _convs(p, xin, Bmat, Cmat,
+                                                 conv_state or {})
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])   # (B,S,H)
-    A = -jnp.exp(p["A_log"])                                       # (H,)
-    a = dt * A                                                     # log decay
-    xh = xin.reshape(B, S, n_heads, head_dim)
-    xdt = (xh.astype(jnp.float32) * dt[..., None]).astype(x.dtype)
+    with jax.named_scope("mamba.ssd"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
+        A = -jnp.exp(p["A_log"])                                      # (H,)
+        a = dt * A                                                # log decay
+        xh = xin.reshape(B, S, n_heads, head_dim)
+        xdt = (xh.astype(jnp.float32) * dt[..., None]).astype(x.dtype)
 
-    y, final_state = ssd_chunked(xdt, a, Bmat.astype(x.dtype),
-                                 Cmat.astype(x.dtype), init_state=ssm_state)
-    y = y + xh * p["D"][None, None, :, None].astype(x.dtype)
-    y = y.reshape(B, S, d_inner)
+        y, final_state = ssd_chunked(xdt, a, Bmat.astype(x.dtype),
+                                     Cmat.astype(x.dtype),
+                                     init_state=ssm_state)
+        y = y + xh * p["D"][None, None, :, None].astype(x.dtype)
+        y = y.reshape(B, S, d_inner)
 
-    # gated RMSNorm then output projection
-    y = rms_norm(y * jax.nn.silu(z), p["norm_w"], eps)
-    out = y @ p["out_proj"]
+    with jax.named_scope("mamba.out"):
+        # gated RMSNorm then output projection
+        y = rms_norm(y * jax.nn.silu(z), p["norm_w"], eps)
+        out = y @ p["out_proj"]
     if return_state:
         return out, (final_state, new_conv_state)
     return out
@@ -187,29 +198,31 @@ def mamba2_decode_step(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Single-token recurrent update.  x: (B,1,D); state (B,H,P,N)."""
     B = x.shape[0]
-    z, xin, Bmat, Cmat, dt = _project(p, x)
+    with jax.named_scope("mamba.proj"):
+        z, xin, Bmat, Cmat, dt = _project(p, x)
 
-    xin, cs_x = _causal_conv(xin, p["conv_x_w"], p["conv_x_b"],
-                             conv_state["x"])
-    Bmat, cs_B = _causal_conv(Bmat, p["conv_B_w"], p["conv_B_b"],
-                              conv_state["B"])
-    Cmat, cs_C = _causal_conv(Cmat, p["conv_C_w"], p["conv_C_b"],
-                              conv_state["C"])
-    new_conv_state = {"x": cs_x, "B": cs_B, "C": cs_C}
+    with jax.named_scope("mamba.conv"):
+        xin, Bmat, Cmat, new_conv_state = _convs(p, xin, Bmat, Cmat,
+                                                 conv_state)
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])[:, 0]  # (B,H)
-    A = -jnp.exp(p["A_log"])
-    decay = jnp.exp(dt * A)                                   # (B,H)
-    xh = xin.reshape(B, n_heads, head_dim).astype(jnp.float32)
-    Bv = Bmat[:, 0].astype(jnp.float32)                       # (B,N)
-    Cv = Cmat[:, 0].astype(jnp.float32)
+    with jax.named_scope("mamba.state"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + p["dt_bias"])[:, 0]            # (B,H)
+        A = -jnp.exp(p["A_log"])
+        decay = jnp.exp(dt * A)                               # (B,H)
+        xh = xin.reshape(B, n_heads, head_dim).astype(jnp.float32)
+        Bv = Bmat[:, 0].astype(jnp.float32)                   # (B,N)
+        Cv = Cmat[:, 0].astype(jnp.float32)
 
-    # h' = decay * h + dt * (x outer B);  y = C . h' + D*x
-    upd = (dt[..., None] * xh)[..., None] * Bv[:, None, None, :]
-    new_state = ssm_state * decay[..., None, None] + upd.astype(ssm_state.dtype)
-    y = jnp.einsum("bhpn,bn->bhp", new_state.astype(jnp.float32), Cv)
-    y = y + xh * p["D"][None, :, None]
-    y = y.reshape(B, 1, d_inner).astype(x.dtype)
+        # h' = decay * h + dt * (x outer B);  y = C . h' + D*x
+        upd = (dt[..., None] * xh)[..., None] * Bv[:, None, None, :]
+        new_state = (ssm_state * decay[..., None, None]
+                     + upd.astype(ssm_state.dtype))
+        y = jnp.einsum("bhpn,bn->bhp", new_state.astype(jnp.float32), Cv)
+        y = y + xh * p["D"][None, :, None]
+        y = y.reshape(B, 1, d_inner).astype(x.dtype)
 
-    y = rms_norm(y * jax.nn.silu(z), p["norm_w"], eps)
-    return y @ p["out_proj"], new_state, new_conv_state
+    with jax.named_scope("mamba.out"):
+        y = rms_norm(y * jax.nn.silu(z), p["norm_w"], eps)
+        out = y @ p["out_proj"]
+    return out, new_state, new_conv_state

@@ -5,7 +5,14 @@ experts, the B*S*k rows are sorted by expert and run through grouped
 matmuls over ragged groups (``kernels.moe_gmm.ragged_matmul``: megablox's
 Pallas ``gmm`` on a TPU), then unsorted and combined with the renormalised
 gates.  The named scopes ``moe.route``, ``moe.gmm`` and ``moe.combine`` mark
-its three parts in the device's op view.
+its three parts in the device's op view, and ``moe.shared`` a shared expert.
+
+Expert parallelism: a layer may hold a share of the experts, the bank's
+``held`` experts from ``first_expert`` on.  It routes over all
+``n_experts``, computes the rows routed to the experts it holds, with their
+gates as routed, and neither computes nor adds the others (the chips that
+hold them would); the layer that holds every expert is the uncut layer.
+A shared expert, which every share computes alike, is added in full.
 """
 
 from __future__ import annotations
@@ -16,21 +23,28 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.moe_gmm.ops import ragged_matmul
-from .layers import dense_init
+from .layers import dense_init, mlp, mlp_params
 
 
-def moe_params(key, d_model: int, d_ff: int, n_experts: int, dtype) -> Dict:
+def moe_params(key, d_model: int, d_ff: int, n_experts: int, dtype,
+               held: int = 0, shared_d_ff: int = 0) -> Dict:
+    """Router over ``n_experts``; banks of the ``held`` experts (0: all);
+    a shared SwiGLU expert of width ``shared_d_ff`` (0: none)."""
     ks = jax.random.split(key, 4)
     def expert_bank(k, a, b):
-        keys = jax.random.split(k, n_experts)
+        keys = jax.random.split(k, held or n_experts)
         return jnp.stack([dense_init(kk, a, b, dtype) for kk in keys])
 
-    return {
+    p = {
         "router": dense_init(ks[0], d_model, n_experts, jnp.float32),
         "wi_gate": expert_bank(ks[1], d_model, d_ff),
         "wi_up": expert_bank(ks[2], d_model, d_ff),
         "wo": expert_bank(ks[3], d_ff, d_model),
     }
+    if shared_d_ff:
+        p["shared_mlp"] = mlp_params(jax.random.fold_in(key, 4), d_model,
+                                     shared_d_ff, dtype)
+    return p
 
 
 def moe_mlp(
@@ -39,11 +53,14 @@ def moe_mlp(
     *,
     n_experts: int,
     top_k: int,
+    first_expert: int = 0,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Returns (output (B,S,D), aux metrics incl. load-balance loss).
 
     ``aux`` counts the (token, expert) rows the experts computed
-    (``moe_rows``) and the most any one expert took (``moe_max_group``).
+    (``moe_rows``) and the most any one expert took (``moe_max_group``);
+    a layer holding a share of the experts also the rows routed to experts
+    it does not hold (``moe_rows_elsewhere``), counted and not computed.
     """
     with jax.named_scope("moe.route"):
         # float32 routing, at full precision: a TPU's default runs a
@@ -62,31 +79,45 @@ def moe_mlp(
     aux = {"moe_lb_loss": jnp.mean(density * router_prob),
            "moe_z_loss": jnp.mean(jnp.square(
                jax.nn.logsumexp(logits, axis=-1)))}
-    out, counts = _dropless(p, x, gate_vals, expert_idx, n_experts)
+    out, counts = _dropless(p, x, gate_vals, expert_idx, n_experts,
+                            first_expert)
     # the group sizes the grouped matmuls take: a row in no group (an
     # expert id outside [0, E)) is not computed and not counted
     aux.update(moe_rows=counts.sum(), moe_max_group=counts.max())
+    if counts.shape[0] < n_experts:
+        aux["moe_rows_elsewhere"] = expert_idx.size - counts.sum()
+    if "shared_mlp" in p:
+        with jax.named_scope("moe.shared"):
+            out = out + mlp(p["shared_mlp"], x)
     return out, aux
 
 
 def _dropless(p: Dict, x: jnp.ndarray, gate_vals: jnp.ndarray,
-              expert_idx: jnp.ndarray, n_experts: int):
-    """Every (token, expert) row through its expert: sort the B*S*k rows
-    by expert, grouped matmuls over the ragged groups, unsort, combine with
-    the gates.  Returns (output (B,S,D), rows per expert int32[E])."""
+              expert_idx: jnp.ndarray, n_experts: int, first: int = 0):
+    """Every (token, expert) row of the held experts through its expert:
+    sort the B*S*k rows by expert, the held experts' first, grouped matmuls
+    over their ragged groups, unsort, combine with the gates.  Returns
+    (output (B,S,D), rows per held expert int32[held])."""
     B, S, D = x.shape
     k = expert_idx.shape[-1]
     R = B * S * k
+    held = p["wi_gate"].shape[0]
     with jax.named_scope("moe.route"):
         flat_e = expert_idx.reshape(R)
+        if first:       # the held experts as ids 0 .. held-1
+            flat_e = (flat_e - first) % n_experts
         order = jnp.argsort(flat_e)                    # rows by expert
-        counts = jnp.bincount(flat_e, length=n_experts).astype(jnp.int32)
+        counts = jnp.bincount(flat_e, length=held).astype(jnp.int32)
         xs = x.reshape(B * S, D)[order // k]           # (R, D) sorted
     with jax.named_scope("moe.gmm"):
         h = jax.nn.silu(ragged_matmul(xs, p["wi_gate"], counts)) \
             * ragged_matmul(xs, p["wi_up"], counts)
         ys = ragged_matmul(h, p["wo"], counts)         # (R, D) sorted
     with jax.named_scope("moe.combine"):
+        if held < n_experts:
+            # the rows past the held experts' are in no group: not computed
+            # (the kernel leaves them unwritten) and not added
+            ys = jnp.where((jnp.arange(R) < counts.sum())[:, None], ys, 0)
         unsort = jnp.zeros((R,), jnp.int32).at[order].set(
             jnp.arange(R, dtype=jnp.int32))
         y = ys[unsort].reshape(B, S, k, D).astype(jnp.float32)

@@ -1,8 +1,12 @@
 """Model assembly: embeddings -> scanned block stack -> LM head.
 
-One composable decoder covers all ten assigned architectures; the layer kind
-comes from ``cfg.block_pattern`` ("attn" | "moe" | "mamba2" | "rwkv6"), with
-three structural extensions:
+One composable decoder covers every architecture of ``repro.configs``; the
+layer kind comes from ``cfg.block_pattern`` ("attn" | "moe" | "mamba2" |
+"mamba2_moe" | "rwkv6"), cycled over the layers.  Each run of same-kind
+layers is one layer scan over its own stacked weights and caches: a model
+of one kind has one run, a hybrid (granite-4.0-h: Mamba2 and attention
+layers, each followed by an MoE) one per run, in published order.  Three
+structural extensions:
 
 * zamba2: a *shared* attention+MLP block (single parameter set) applied every
   ``cfg.shared_attn_every`` SSM layers - handled inside the layer scan with
@@ -65,6 +69,17 @@ def _attn_block_params(cfg: ModelConfig, key, cross: bool, dtype) -> Params:
     return p
 
 
+def _moe_params(cfg: ModelConfig, key, dtype) -> Params:
+    return MOE.moe_params(key, cfg.d_model, cfg.moe_d_ff, cfg.n_experts,
+                          dtype, held=cfg.experts_held,
+                          shared_d_ff=cfg.shared_d_ff)
+
+
+def _by_run(stacks: tuple):
+    """A model's "layers": the one run's stack, or a tuple of the runs'."""
+    return stacks[0] if len(stacks) == 1 else stacks
+
+
 def _layer_params(cfg: ModelConfig, kind: str, key, dtype,
                   decoder: bool = True) -> Params:
     ks = jax.random.split(key, 2)
@@ -74,16 +89,19 @@ def _layer_params(cfg: ModelConfig, kind: str, key, dtype,
         return p
     if kind == "moe":
         p = _attn_block_params(cfg, ks[0], False, dtype)
-        p["moe"] = MOE.moe_params(ks[1], cfg.d_model, cfg.moe_d_ff,
-                                  cfg.n_experts, dtype)
+        p["moe"] = _moe_params(cfg, ks[1], dtype)
         return p
-    if kind == "mamba2":
-        return {
+    if kind in ("mamba2", "mamba2_moe"):
+        p = {
             "ln1": L.rms_norm_init(cfg.d_model, dtype),
             "mamba": M.mamba2_params(ks[0], cfg.d_model, cfg.d_inner,
                                      cfg.ssm_state, cfg.ssm_heads,
                                      cfg.ssm_conv, dtype),
         }
+        if kind == "mamba2_moe":
+            p["ln2"] = L.rms_norm_init(cfg.d_model, dtype)
+            p["moe"] = _moe_params(cfg, ks[1], dtype)
+        return p
     if kind == "rwkv6":
         return {
             "ln1": L.rms_norm_init(cfg.d_model, dtype),
@@ -96,7 +114,6 @@ def _layer_params(cfg: ModelConfig, kind: str, key, dtype,
 
 def init_params(cfg: ModelConfig, key) -> Params:
     dtype = jnp.dtype(cfg.dtype)
-    kind = cfg.block_pattern[0]
     keys = jax.random.split(key, 8)
 
     params: Params = {
@@ -107,8 +124,10 @@ def init_params(cfg: ModelConfig, key) -> Params:
     }
 
     layer_keys = jax.random.split(keys[2], cfg.n_layers)
-    params["layers"] = jax.vmap(
-        lambda k: _layer_params(cfg, kind, k, dtype))(layer_keys)
+    params["layers"] = _by_run(tuple(
+        jax.vmap(lambda k, kind=kind: _layer_params(cfg, kind, k, dtype))(
+            layer_keys[first:first + n])
+        for kind, first, n in cfg.layer_runs()))
 
     if cfg.shared_attn_every:
         p = _attn_block_params(cfg, keys[3], False, dtype)
@@ -158,37 +177,45 @@ def _attn_cache(cfg: ModelConfig, B: int, max_len: int, dtype) -> Dict:
 
 def init_cache(cfg: ModelConfig, B: int, max_len: int,
                enc_len: int = 0) -> Dict:
-    """Zeros cache pytree (use jax.eval_shape on this for the dry-run)."""
+    """Zeros cache pytree (use jax.eval_shape on this for the dry-run).
+
+    "layers" holds each run's stacked per-layer caches (see ``_by_run``):
+    K/V rings for attention layers, SSM and conv states for Mamba2 layers,
+    WKV state and shifts for RWKV6 layers; a hybrid holds both kinds side by
+    side, one stack per run."""
     dtype = jnp.dtype(cfg.dtype)
-    kind = cfg.block_pattern[0]
     Ld = cfg.n_layers
 
-    def stack(tree_fn):
+    def stack(n, tree_fn):
         one = tree_fn()
         return jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (Ld,) + a.shape), one)
+            lambda a: jnp.broadcast_to(a[None], (n,) + a.shape), one)
 
-    cache: Dict[str, Any] = {"pos": jnp.zeros((), jnp.int32)}
-    if kind in ("attn", "moe"):
-        cache["layers"] = stack(lambda: _attn_cache(cfg, B, max_len, dtype))
-    elif kind == "mamba2":
-        kconv = cfg.ssm_conv - 1
-        cache["layers"] = stack(lambda: {
-            "ssm": jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim,
-                              cfg.ssm_state), jnp.float32),
-            "conv": {
-                "x": jnp.zeros((B, kconv, cfg.d_inner), dtype),
-                "B": jnp.zeros((B, kconv, cfg.ssm_state), dtype),
-                "C": jnp.zeros((B, kconv, cfg.ssm_state), dtype),
-            },
-        })
-    elif kind == "rwkv6":
+    def layer_cache(kind):
+        if kind in ("attn", "moe"):
+            return _attn_cache(cfg, B, max_len, dtype)
+        if kind in ("mamba2", "mamba2_moe"):
+            kconv = cfg.ssm_conv - 1
+            return {
+                "ssm": jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim,
+                                  cfg.ssm_state), jnp.float32),
+                "conv": {
+                    "x": jnp.zeros((B, kconv, cfg.d_inner), dtype),
+                    "B": jnp.zeros((B, kconv, cfg.ssm_state), dtype),
+                    "C": jnp.zeros((B, kconv, cfg.ssm_state), dtype),
+                },
+            }
         P = cfg.rwkv_head_dim
-        cache["layers"] = stack(lambda: {
+        return {
             "wkv": jnp.zeros((B, cfg.rwkv_heads, P, P), jnp.float32),
             "tm_shift": jnp.zeros((B, 1, cfg.d_model), dtype),
             "cm_shift": jnp.zeros((B, 1, cfg.d_model), dtype),
-        })
+        }
+
+    cache: Dict[str, Any] = {"pos": jnp.zeros((), jnp.int32)}
+    cache["layers"] = _by_run(tuple(
+        stack(n, lambda kind=kind: layer_cache(kind))
+        for kind, _, n in cfg.layer_runs()))
     if cfg.shared_attn_every:
         n_inv = cfg.n_layers // cfg.shared_attn_every
         one = _attn_cache(cfg, B, max_len, dtype)
@@ -230,7 +257,7 @@ def _apply_attn_block(cfg, p, x, positions, cache, cache_pos, *, decode,
     attn_out, new_cache = L.multihead_attention(
         p["attn"], h, positions, None, cache, cache_pos,
         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, d_head=cfg.head_dim,
-        qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
+        qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta if cfg.rope else None,
         window=cfg.sliding_window, causal=causal, decode=decode,
         eps=cfg.norm_eps, q_scale=q_scale, sc=sc)
     x = sc(x + _scaled(attn_out, res), "residual")
@@ -245,17 +272,26 @@ def _apply_attn_block(cfg, p, x, positions, cache, cache_pos, *, decode,
             sc=sc)
         x = sc(x + _scaled(cross_out, res), "residual")
 
-    h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if "moe" in p:
-        moe_out, aux = MOE.moe_mlp(p["moe"], h2, n_experts=cfg.n_experts,
-                                   top_k=cfg.n_experts_active)
-        x = sc(x + _scaled(moe_out, res), "residual")
-    else:
-        x = sc(x + _scaled(L.mlp(p["mlp"], h2), res), "residual")
+    x, aux = _apply_mlp(cfg, p, x, sc)
     return x, new_cache, new_cross, aux
 
 
+def _apply_mlp(cfg, p, x, sc):
+    """The block's MLP after its norm: dense, or the MoE (with its shared
+    expert); then the residual add.  Returns (x, aux)."""
+    aux: Dict[str, jnp.ndarray] = {}
+    h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+    if "moe" in p:
+        out, aux = MOE.moe_mlp(p["moe"], h2, n_experts=cfg.n_experts,
+                               top_k=cfg.n_experts_active)
+    else:
+        out = L.mlp(p["mlp"], h2)
+    return sc(x + _scaled(out, cfg.residual_multiplier), "residual"), aux
+
+
 def _apply_mamba_block(cfg, p, x, cache, *, decode, sc):
+    """Mamba2 mixer (+ the MoE of "mamba2_moe").  Returns (x, new_cache,
+    aux)."""
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     kw = dict(d_inner=cfg.d_inner, n_state=cfg.ssm_state,
               n_heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
@@ -272,7 +308,11 @@ def _apply_mamba_block(cfg, p, x, cache, *, decode, sc):
     else:
         out = M.mamba2_forward(p["mamba"], h, **kw)
         new_cache = None
-    return sc(x + out, "residual"), new_cache
+    x = sc(x + _scaled(out, cfg.residual_multiplier), "residual")
+    aux: Dict[str, jnp.ndarray] = {}
+    if "moe" in p:
+        x, aux = _apply_mlp(cfg, p, x, sc)
+    return x, new_cache, aux
 
 
 def _apply_rwkv_block(cfg, p, x, cache, *, decode, sc):
@@ -311,27 +351,78 @@ def _apply_rwkv_block(cfg, p, x, cache, *, decode, sc):
 
 def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
            cache_pos, *, decode: bool, cross_src, sc, remat: bool):
-    """Run the decoder stack.  caches: stacked per-layer cache or None.
+    """Run the decoder stack: one layer scan per run of same-kind layers
+    (``cfg.layer_runs()``).  caches: the stacked per-layer caches or None.
 
     In decode the K/V rings are read-only in the scan and not among its
     xs: each layer reads its ring from the stack by index (see
     ``layers.multihead_attention``) and hands back its new tokens' k/v,
     written into the stacked ring after the scan, so a step over a donated
     cache writes those tokens and copies no cache, nor any layer's ring.
-    mamba2/rwkv6 states are rewritten whole every step."""
-    kind = cfg.block_pattern[0]
-    rings = decode and kind in ("attn", "moe")
-    layer_caches = caches["layers"] if caches is not None else None
+    Mamba2 states ride in the scan's carry: each layer reads its states
+    from the stack by index and writes its new ones over them, so a step
+    rewrites each state once, in place.  rwkv6 states are rewritten whole
+    every step."""
+    runs = cfg.layer_runs()
+    one = len(runs) == 1
+    layer_params = (params["layers"],) if one else params["layers"]
+    run_caches = (None if caches is None else
+                  (caches["layers"],) if one else caches["layers"])
     cross_caches = caches.get("cross") if (caches is not None
                                            and cfg.is_encdec) else None
-    shared0 = caches.get("shared") if (caches is not None
-                                       and cfg.shared_attn_every) else None
+    shared = caches.get("shared") if (caches is not None
+                                      and cfg.shared_attn_every) else None
+
+    new_runs, auxes = [], []
+    for r, (kind, first, n) in enumerate(runs):
+        x, shared, new_lcache, aux = _run(
+            cfg, params, kind, first, n, layer_params[r],
+            None if caches is None else run_caches[r], x, shared, positions,
+            cache_pos, decode=decode, cross_caches=cross_caches,
+            cross_src=cross_src, sc=sc, remat=remat)
+        new_runs.append(new_lcache)
+        auxes.append(aux)
+    aux = auxes[0] if one else {
+        k: jnp.concatenate([a[k] for a in auxes if k in a])
+        for k in dict.fromkeys(k for a in auxes for k in a)}
+
+    new_caches = None
+    if caches is not None:
+        layers_out = [c for c, _ in new_runs]
+        new_caches = dict(caches)
+        new_caches["layers"] = _by_run(tuple(layers_out))
+        if cfg.is_encdec and not decode:
+            new_caches["cross"] = new_runs[0][1]
+        if cfg.shared_attn_every:
+            new_caches["shared"] = shared
+    # aux scanned outputs: MoE rows summed and the largest group maxed over
+    # layers, the rest averaged
+    reduce = {"moe_rows": jnp.sum, "moe_max_group": jnp.max,
+              "moe_rows_elsewhere": jnp.sum}
+    aux = {k: reduce.get(k, jnp.mean)(v) for k, v in aux.items()}
+    return x, new_caches, aux
+
+
+def _run(cfg: ModelConfig, params: Params, kind: str, first: int, n: int,
+         run_params, layer_caches, x, shared0, positions, cache_pos, *,
+         decode: bool, cross_caches, cross_src, sc, remat: bool):
+    """One run of ``n`` layers of ``kind`` from layer ``first`` on, as one
+    layer scan.  Returns (x, zamba2's shared cache, (the run's new caches,
+    whisper's new cross caches), the scanned aux)."""
+    rings = decode and kind in ("attn", "moe")
+    states = decode and kind in ("mamba2", "mamba2_moe")
 
     def unit(carry, xs):
-        x, shared_cache = carry
+        x, shared_cache, *carried = carry
         lp, lcache, idx, lcross = xs
+        # the layer's index in its run's stacks (idx itself in a first run)
+        local = idx - first if first else idx
         if rings:
-            lcache = dict(layer_caches, index=idx)
+            lcache = dict(layer_caches, index=local)
+        if states:
+            lcache = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, local, 0, False),
+                carried[0])
         if decode and cross_caches is not None:
             lcross = dict(cross_caches, index=idx)
         aux = {}
@@ -340,14 +431,20 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
                 cfg, lp, x, positions, lcache, cache_pos,
                 decode=decode, causal=True, cross_src=cross_src,
                 cross_cache=lcross, sc=sc)
-        elif kind == "mamba2":
-            x, new_lcache = _apply_mamba_block(cfg, lp, x, lcache,
-                                               decode=decode, sc=sc)
+        elif kind in ("mamba2", "mamba2_moe"):
+            x, new_lcache, aux = _apply_mamba_block(cfg, lp, x, lcache,
+                                                    decode=decode, sc=sc)
             new_lcross = lcross
         else:
             x, new_lcache = _apply_rwkv_block(cfg, lp, x, lcache,
                                               decode=decode, sc=sc)
             new_lcross = lcross
+        if states:      # the new states over the old, in the carry
+            carried = [jax.tree.map(
+                lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                    a, u.astype(a.dtype), local, 0),
+                carried[0], new_lcache)]
+            new_lcache = None
 
         # zamba2 shared attention block every k layers
         if cfg.shared_attn_every:
@@ -385,34 +482,25 @@ def _stack(cfg: ModelConfig, params: Params, x, positions, caches,
                 (idx + 1) % k == 0, with_shared, without_shared,
                 (x, shared_cache))
 
-        return (x, shared_cache), (new_lcache, new_lcross, aux)
+        return (x, shared_cache, *carried), (new_lcache, new_lcross, aux)
 
     unit_fn = jax.checkpoint(unit) if remat else unit
 
-    idxs = jnp.arange(cfg.n_layers)
-    xs = (params["layers"], None if rings else layer_caches, idxs,
+    idxs = jnp.arange(first, first + n)
+    xs = (run_params, None if rings or states else layer_caches, idxs,
           None if decode else cross_caches)
-    (x, shared_out), (new_layer_caches, new_cross, aux) = jax.lax.scan(
-        unit_fn, (x, shared0), xs)
+    carry = (x, shared0) + ((layer_caches,) if states else ())
+    (x, shared_out, *carried), (new_layer_caches, new_cross, aux) = \
+        jax.lax.scan(unit_fn, carry, xs)
 
-    new_caches = None
-    if caches is not None:
-        if rings:
-            # ys holds the new tokens (L, B, S, kv*dh): the ring's T is axis 2
-            new_layer_caches = jax.tree.map(
-                lambda buf, new: L.ring_update(buf, new, cache_pos, axis=2),
-                layer_caches, new_layer_caches)
-        new_caches = dict(caches)
-        new_caches["layers"] = new_layer_caches
-        if cfg.is_encdec and not decode:
-            new_caches["cross"] = new_cross
-        if cfg.shared_attn_every:
-            new_caches["shared"] = shared_out
-    # aux scanned outputs: MoE rows summed and the largest group maxed over
-    # layers, the rest averaged
-    reduce = {"moe_rows": jnp.sum, "moe_max_group": jnp.max}
-    aux = {k: reduce.get(k, jnp.mean)(v) for k, v in aux.items()}
-    return x, new_caches, aux
+    if states:
+        new_layer_caches = carried[0]
+    elif rings:
+        # ys holds the new tokens (L, B, S, kv*dh): the ring's T is axis 2
+        new_layer_caches = jax.tree.map(
+            lambda buf, new: L.ring_update(buf, new, cache_pos, axis=2),
+            layer_caches, new_layer_caches)
+    return x, shared_out, (new_layer_caches, new_cross), aux
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,9 @@ Scheme (DESIGN.md section 5):
   query head count in train/prefill - the MaxText "kv replication" trick -
   so one mesh axis shards one dim); MLP column->row; vocab on the model axis
   for both embedding and LM head.
-* **EP** over ``model`` for MoE expert banks when n_experts divides the axis
-  (granite 32e); otherwise TP inside experts (mixtral 8e on 16 shards).
+* **EP** over ``model`` for MoE expert banks when the experts held divide
+  the axis (granite 32e); otherwise TP inside experts (mixtral 8e on 16
+  shards).  A shared expert is sharded as a dense MLP.
 * **SP**: the train/prefill residual stream is sharded (dp, model, None) on
   (B, S, D) - Megatron-style sequence parallelism; XLA inserts the
   all-gather/reduce-scatter pairs around attention/MLP.
@@ -129,7 +130,7 @@ class ShardingRules:
             if name == "wo":
                 return spec_from({-2: tp} if heads_ok else {})
             return P(*([None] * nd))              # q_norm / k_norm
-        if parent == "mlp":
+        if parent in ("mlp", "shared_mlp"):
             if name in ("wi_gate", "wi_up"):
                 return spec_from({-1: tp})
             if name == "wo":
@@ -137,7 +138,7 @@ class ShardingRules:
         if parent == "moe":
             if name == "router":
                 return P(*([None] * nd))
-            ep = self.cfg.n_experts % self.tp_size == 0
+            ep = self.cfg.n_experts_held % self.tp_size == 0
             if ep:
                 return spec_from({-3: tp})        # expert-parallel bank
             if name in ("wi_gate", "wi_up"):

@@ -1,4 +1,4 @@
-"""Continuous-batching serving engine with GCR admission.
+"""Serving engines with GCR admission.
 
 Two engines share the scheduler/admission machinery:
 
@@ -11,9 +11,10 @@ Two engines share the scheduler/admission machinery:
   parks excess streams and keeps throughput at the peak - the Figure 6
   phenomenology at the serving layer.
 
-* ``JaxServeEngine`` - drives a real model (prefill + decode_step) with slot
-  management over a fixed batch; used by the examples and integration tests
-  on CPU with the reduced configs.
+* ``JaxServeEngine`` - drives a real model (prefill + decode_step) in
+  closed waves over a fixed batch of slots: the benchmark's path on the chip
+  (``bench/``, ``chip_smoke.py``, ``launch/serve.py``), and the examples'
+  and tests' on the CPU with the reduced configs.
 
 Step-cost model (per decode step over the active batch):
     t = t_fixed + t_tok * B_active + t_kv * (KV_resident / B_active ...)
@@ -878,7 +879,7 @@ def make_admission(kind: str, active_limit: int, n_pods: int = 2,
 
 
 # ---------------------------------------------------------------------------
-# Real-model engine (CPU examples / integration tests)
+# Real-model engine (the benchmark's path)
 # ---------------------------------------------------------------------------
 
 # Host spans of ``JaxServeEngine.generate`` in the profiler's trace
@@ -916,23 +917,27 @@ class MoEStats:
     """What the MoE layers of ``JaxServeEngine.generate``'s steps computed:
     (token, expert) rows summed over layers and steps (B*S*k*layers a step
     when nothing is dropped), and the most rows one expert of one layer took
-    in one step."""
+    in one step.  Where the layers hold a share of the experts,
+    ``rows_elsewhere`` counts the rows routed to experts not held here,
+    counted and not computed: ``rows + rows_elsewhere`` is B*S*k*layers."""
 
     rows: int = 0
     max_group: int = 0
+    rows_elsewhere: int = 0
 
 
 class JaxServeEngine:
     """Batched decode over a real model with fixed slots + GCR admission.
 
     The batch has ``n_slots`` lanes; admitted streams occupy lanes, parked
-    streams wait in the GCR queue.  Prefill is per-stream (lane-local cache
-    fill is emulated by re-prefilling the lane batch on admission - adequate
-    for the reduced CPU configs the examples run).  Both step programs take
+    streams wait in the GCR queue.  Each wave prefills its admitted lanes
+    as one batch and decodes them together for ``gen_len`` steps; a stream
+    parked past the slots waits for the next wave.  Both step programs take
     the greedy token on the device, so a wave's tokens stay there until it
     ends.  ``stats`` counts the work done, ``moe_stats`` the rows a model
-    with MoE layers computed; ``generate`` marks its phases with the
-    ``SPAN_*`` spans."""
+    with MoE layers (after attention or a Mamba2 mixer) computed, and those
+    routed to experts held elsewhere; ``generate`` marks its phases with
+    the ``SPAN_*`` spans."""
 
     def __init__(self, cfg, params, n_slots: int, max_len: int,
                  admission_kind: str = "gcr", promote_every: int = 16):
@@ -949,17 +954,21 @@ class JaxServeEngine:
                                         promote_every=promote_every)
         self.stats = EngineStats()
         self.moe_stats = MoEStats()
-        moe = "moe" in cfg.layer_kinds()
+        moe = cfg.n_experts > 0
 
         def greedy(logits):
             return jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
 
         def outputs(logits, c, aux=None):
-            """(token, cache), and for MoE layers int32[rows, max_group]."""
+            """(token, cache), and for MoE layers int32[rows, max_group],
+            with rows_elsewhere last where the layers hold a share of the
+            experts."""
             if aux is None:
                 return greedy(logits), c
-            return greedy(logits), c, jnp.stack(
-                [aux["moe_rows"], aux["moe_max_group"]]).astype(jnp.int32)
+            counts = [aux["moe_rows"], aux["moe_max_group"]]
+            if "moe_rows_elsewhere" in aux:
+                counts.append(aux["moe_rows_elsewhere"])
+            return greedy(logits), c, jnp.stack(counts).astype(jnp.int32)
 
         # named, so the device's trace names the programs after them
         def serve_decode(p, c, t):
@@ -1042,8 +1051,10 @@ class JaxServeEngine:
                 for t, col in enumerate(served):
                     out[active, t] = col[:, 0]
                 if counts:
-                    rows, groups = np.stack(counts).T
+                    rows, groups, *elsewhere = np.stack(counts).T
                     self.moe_stats.rows += int(rows.sum(dtype=np.int64))
+                    self.moe_stats.rows_elsewhere += sum(
+                        int(e.sum(dtype=np.int64)) for e in elsewhere)
                     self.moe_stats.max_group = max(self.moe_stats.max_group,
                                                    int(groups.max()))
                 stats.decode_steps += gen_len

@@ -49,6 +49,8 @@ _PHASES = textwrap.dedent("""
                              slots=2, prompt_len=1024, gen_len=4)
     chip_smoke.check_serving(clock, "granite-moe-1b-a400m", smoke=True,
                              streams=4, slots=2, prompt_len=64, gen_len=4)
+    chip_smoke.check_serving(clock, "granite-4.0-h-small", smoke=True,
+                             streams=4, slots=2, prompt_len=64, gen_len=4)
     # the real-width kernel cases at toy shapes, interpreted
     small = {"flash_attention": (((1, 256, 2, 128), "bfloat16"),) * 3,
              "mamba2_ssd": (((1, 256, 2, 64), "float32"),
@@ -86,7 +88,10 @@ def test_phases_pass_at_smoke_size():
     assert "phase train_sharded" in proc.stdout
     assert "phase prefill_vs_forward" in proc.stdout
     assert "serve: moe_rows=1088 (no drop: 1088)" in proc.stdout
-    assert proc.stdout.count("serve: decode alias_bytes=") == 2
+    # the hybrid: 4 streams x (64 + 4) tokens x top-3 x 4 layers, of which
+    # the rows routed to the 4 experts held elsewhere are counted only
+    assert "(no drop: 3264) moe_rows_elsewhere=" in proc.stdout
+    assert proc.stdout.count("serve: decode alias_bytes=") == 3
 
 
 _CACHE_DIR = textwrap.dedent("""

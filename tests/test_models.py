@@ -95,7 +95,8 @@ def _decode_errors(cfg, B=2, S=24, EXTRA=4):
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "zamba2-2.7b", "rwkv6-7b",
                                   "whisper-base", "mixtral-8x7b",
-                                  "internvl2-2b", "granite-moe-1b-a400m"])
+                                  "internvl2-2b", "granite-moe-1b-a400m",
+                                  "granite-4.0-h-small"])
 def test_decode_matches_teacher_forcing(arch):
     """prefill+decode logits == full forward at the same positions
     (catches cache/rope/state bugs)."""
@@ -178,6 +179,8 @@ def test_full_configs_match_assignment():
         "internvl2-2b": (24, 2048, 16, 8, 8192, 92553),
         "mixtral-8x7b": (32, 4096, 32, 8, 14336, 32000),
         "granite-moe-1b-a400m": (24, 1024, 16, 8, 512, 49155),
+        # one period of 10 layers of the published 40 (the chip's share)
+        "granite-4.0-h-small": (10, 4096, 32, 8, 768, 100352),
     }
     for arch, (L_, d, h, kv, ff, v) in spec.items():
         cfg = get_config(arch)
@@ -190,3 +193,17 @@ def test_full_configs_match_assignment():
     assert get_config("granite-moe-1b-a400m").n_experts == 32
     assert get_config("granite-moe-1b-a400m").n_experts_active == 8
     assert get_config("mixtral-8x7b").sliding_window == 4096
+    g4 = get_config("granite-4.0-h-small")
+    assert (g4.n_experts, g4.experts_held, g4.n_experts_active,
+            g4.shared_d_ff) == (72, 9, 10, 1536)
+    assert (g4.ssm_heads, g4.ssm_head_dim, g4.ssm_state, g4.ssm_conv,
+            g4.head_dim, g4.rope) == (128, 64, 128, 4, 128, False)
+    assert g4.layer_kinds() == ("mamba2_moe",) * 5 + ("moe",) \
+        + ("mamba2_moe",) * 4
+    assert (g4.embedding_multiplier, g4.attention_multiplier,
+            g4.residual_multiplier, g4.logits_scaling) \
+        == (12.0, 0.0078125, 0.22, 16.0)
+    from repro.configs.granite_4_0_h_small import PUBLISHED
+    assert PUBLISHED.n_layers == 40 and PUBLISHED.n_experts_held == 72
+    assert [i for i, k in enumerate(PUBLISHED.layer_kinds())
+            if k == "moe"] == [5, 15, 25, 35]

@@ -64,7 +64,8 @@ def test_param_specs_divisible(arch, multi_pod):
 
 
 @pytest.mark.parametrize("arch", ["internlm2-20b", "rwkv6-7b",
-                                  "mixtral-8x7b", "zamba2-2.7b"])
+                                  "mixtral-8x7b", "zamba2-2.7b",
+                                  "granite-4.0-h-small"])
 def test_cache_specs_divisible(arch):
     cfg = get_config(arch)
     rules = _rules(arch)

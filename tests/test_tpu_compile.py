@@ -97,3 +97,44 @@ def test_decode_cache_is_lane_dense_for_v5e(arch, slots, one_chip,
                        cfg.n_kv_heads * cfg.head_dim)
     assert 0 <= mem.alias_size_in_bytes - nbytes(k) - nbytes(v) <= 512
     assert mem.temp_size_in_bytes < nbytes(k) // cfg.n_layers
+
+
+def test_hybrid_decode_takes_its_cache_in_place_for_v5e(one_chip,
+                                                        monkeypatch):
+    """granite-4.0-h-small's decode program at full width, at the cell's 16
+    slots and ring: the whole hybrid cache (each run's Mamba2 SSM and conv
+    states, the attention layer's K/V ring, ``pos``) is aliased to the
+    output, within ``pos``'s one 512-byte tile, and the temporaries hold
+    less than one layer's SSM state: each state is rewritten in place, and
+    no state or ring is copied out of its stack."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.kernels.moe_gmm import ops as gmm
+    from repro.kernels.ring_decode import ops as ring
+    from repro.models import init_params, layers, moe
+    from repro.serving.engine import JaxServeEngine
+
+    monkeypatch.setattr(moe, "ragged_matmul",
+                        functools.partial(gmm.ragged_matmul, impl="pallas"))
+    monkeypatch.setattr(layers, "ring_decode",
+                        functools.partial(ring.ring_decode, impl="pallas"))
+    cfg, slots = get_config("granite-4.0-h-small"), 16
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    eng = JaxServeEngine(cfg, params, n_slots=slots, max_len=1024 + 128 + 4)
+    batch = {"tokens": jax.ShapeDtypeStruct((slots, 1024), jnp.int32)}
+    tok, cache = jax.eval_shape(eng.prefill_fn, params, batch)[:2]
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    mem = eng.decode_fn.lower(on_chip(params), on_chip(cache),
+                              on_chip(tok)).compile().memory_analysis()
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    assert [("ssm" in r) for r in cache["layers"]] == [True, False, True]
+    state = cache["layers"][0]["ssm"]
+    assert state.shape == (5, slots, 128, 64, 128)
+    total = sum(nbytes(a) for a in jax.tree.leaves(cache))
+    assert 0 <= mem.alias_size_in_bytes - total <= 512
+    assert mem.temp_size_in_bytes < nbytes(state) // state.shape[0]
