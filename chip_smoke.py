@@ -19,8 +19,9 @@ expert held elsewhere, and that the decode program updates its cache in
 place (every step took its input cache; ``memory_analysis()``: the whole
 cache, K/V rings and SSM and conv states alike, aliased to the output, no
 stacked cache buffer among the temporaries).  Then it runs each case of
-``repro.kernels.cases`` natively against its ``ref.py`` oracle: the three
-Pallas kernels of ``repro.kernels`` and the MoE's megablox ``gmm`` at
+``repro.kernels.cases`` natively against its ``ref.py`` oracle: the
+Pallas kernels of ``repro.kernels``, among them the MoE combine at both MoE
+cells' prefill widths, and the MoE's megablox ``gmm`` at
 granite-moe-1b-a400m's and mixtral-8x7b's widths.
 
 ``--four-chips``: three steps of qwen3-0.6b training at full width, built as

@@ -5,8 +5,8 @@ Every op takes ``impl``: ``pallas`` runs the kernel natively, ``interpret``
 runs it in the Pallas interpreter (how the tests check it on a CPU), ``ref``
 runs the plain oracle, and ``auto`` means ``pallas`` on a TPU and is an
 error anywhere else, so no caller falls back to the interpreter unawares;
-``moe_gmm``'s and ``ring_decode``'s ``auto`` is ``ref`` off a TPU, because
-the model calls them.
+``moe_gmm``'s, ``moe_combine``'s and ``ring_decode``'s ``auto`` is ``ref``
+off a TPU, because the model calls them.
 """
 
 import jax

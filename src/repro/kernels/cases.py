@@ -3,9 +3,11 @@
 The TPU compile tests (tests/test_tpu_compile.py) compile these shapes for
 a described v5e, and ``chip_smoke.py`` runs them on the chip against the
 ``ref.py`` oracles.  ``moe_ragged_gmm`` and ``moe_ragged_gmm_mixtral``
-are the dropless MoE's grouped matmul; ``ring_decode_granite`` and
-``ring_decode_qwen3`` the decode step's attention over the K/V ring at the
-benchmark cells' lanes and rings.
+are the dropless MoE's grouped matmul; ``moe_combine_granite`` and
+``moe_combine_granite4h`` its combine at the MoE cells' prefill (32 lanes
+and 16 of 1024 tokens); ``ring_decode_granite`` and ``ring_decode_qwen3``
+the decode step's attention over the K/V ring at the benchmark cells' lanes
+and rings.
 """
 
 from __future__ import annotations
@@ -23,6 +25,9 @@ from .flash_attention.ops import flash_attention
 from .flash_attention.ref import attention_ref
 from .mamba2_ssd.ops import ssd
 from .mamba2_ssd.ref import ssd_ref
+from .moe_combine.kernel import TILE
+from .moe_combine.ops import group_rows, moe_combine
+from .moe_combine.ref import moe_combine_ref
 from .moe_gmm.ops import ragged_matmul
 from .moe_gmm.ref import ragged_ref
 from .ring_decode.ops import ring_decode
@@ -57,6 +62,7 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
     rwkv = get_config("rwkv6-7b")
     mixtral = get_config("mixtral-8x7b")
     granite = get_config("granite-moe-1b-a400m")
+    granite4h = get_config("granite-4.0-h-small")
 
     # flash attention: qwen3-0.6b heads at a 4k context
     qkv = ((1, 4096, qwen.n_heads, qwen.head_dim), "bfloat16")
@@ -107,6 +113,32 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
         return [_normal(k[0], *shapes[0]), _normal(k[1], *shapes[1], 0.05),
                 sizes]
 
+    # the MoE combine over a prefill's rows: a uniform top-k routing, the
+    # rows past the held experts' (never computed) NaN
+    def combine_draw(key, shapes, n_experts):
+        (R, D), (T, k) = shapes[0][0], shapes[1][0]
+        held = shapes[3][0][1]
+        ks = jax.random.split(key, 3)
+        experts = jnp.argsort(jax.random.uniform(ks[0], (T, n_experts)),
+                              axis=1)[:, :k].astype(jnp.int32)
+        counts, pos, bounds = group_rows(experts, held)
+        gates = jax.random.uniform(ks[1], (T, k), jnp.float32)
+        ys = _normal(ks[2], (R, D), jnp.bfloat16)
+        ys = jnp.where(jnp.arange(R)[:, None] < counts.sum(), ys, jnp.nan)
+        return [ys, pos, gates / gates.sum(1, keepdims=True), bounds]
+
+    def combine_case(name, cfg, tokens):
+        k, D, held = cfg.n_experts_active, cfg.d_model, cfg.n_experts_held
+        shapes = (((tokens * k, D), "bfloat16"), ((tokens, k), "int32"),
+                  ((tokens, k), "float32"),
+                  ((-(-tokens // TILE) + 1, held), "int32"))
+        # the slab table is the kernel's alone
+        return KernelCase(name, moe_combine,
+                          lambda ys, pos, gates, _: moe_combine_ref(
+                              ys, pos, gates), shapes,
+                          functools.partial(combine_draw,
+                                            n_experts=cfg.n_experts))
+
     # decode attention over one ring of a stack of two, as the benchmark
     # cells hold it: 1024-token prompts + 128 tokens out in a 1168-slot ring
     # (1156 rounded to whole tiles), one new token at 15/16 of the ring
@@ -143,6 +175,8 @@ def real_width_cases() -> Tuple[KernelCase, ...]:
                    ragged_shapes, ragged_draw),
         KernelCase("moe_ragged_gmm_mixtral", ragged_matmul, ragged_ref,
                    mixtral_shapes, ragged_draw),
+        combine_case("moe_combine_granite", granite, 32 * 1024),
+        combine_case("moe_combine_granite4h", granite4h, 16 * 1024),
         ring_case("ring_decode_granite", granite, 32),
         ring_case("ring_decode_qwen3", qwen, 16),
     )

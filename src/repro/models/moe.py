@@ -3,9 +3,11 @@
 Every (token, expert) row is computed: the router picks each token's top-k
 experts, the B*S*k rows are sorted by expert and run through grouped
 matmuls over ragged groups (``kernels.moe_gmm.ragged_matmul``: megablox's
-Pallas ``gmm`` on a TPU), then unsorted and combined with the renormalised
-gates.  The named scopes ``moe.route``, ``moe.gmm`` and ``moe.combine`` mark
-its three parts in the device's op view, and ``moe.shared`` a shared expert.
+Pallas ``gmm`` on a TPU), then gathered back per token and combined with the
+renormalised gates (``kernels.moe_combine``: a Pallas gather-reduce on a TPU
+in prefill).  The named scopes ``moe.route``, ``moe.gmm`` and
+``moe.combine`` mark its three parts in the device's op view, and
+``moe.shared`` a shared expert.
 
 Expert parallelism: a layer may hold a share of the experts, the bank's
 ``held`` experts from ``first_expert`` on.  It routes over all
@@ -22,6 +24,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.moe_combine.ops import group_rows, moe_combine
 from ..kernels.moe_gmm.ops import ragged_matmul
 from .layers import dense_init, mlp, mlp_params
 
@@ -96,30 +99,27 @@ def _dropless(p: Dict, x: jnp.ndarray, gate_vals: jnp.ndarray,
               expert_idx: jnp.ndarray, n_experts: int, first: int = 0):
     """Every (token, expert) row of the held experts through its expert:
     sort the B*S*k rows by expert, the held experts' first, grouped matmuls
-    over their ragged groups, unsort, combine with the gates.  Returns
-    (output (B,S,D), rows per held expert int32[held])."""
+    over their ragged groups, then each token's rows gathered back by their
+    sorted positions and combined with the gates.  Returns (output (B,S,D),
+    rows per held expert int32[held])."""
     B, S, D = x.shape
     k = expert_idx.shape[-1]
-    R = B * S * k
     held = p["wi_gate"].shape[0]
     with jax.named_scope("moe.route"):
-        flat_e = expert_idx.reshape(R)
+        flat_e = expert_idx.reshape(B * S, k)
         if first:       # the held experts as ids 0 .. held-1
             flat_e = (flat_e - first) % n_experts
-        order = jnp.argsort(flat_e)                    # rows by expert
-        counts = jnp.bincount(flat_e, length=held).astype(jnp.int32)
+        # group sizes and sorted positions from one cumulative sum: no
+        # scatter; rows routed past the held experts are in no group
+        counts, pos, bounds = group_rows(flat_e, held)
+        order = jnp.argsort(flat_e.reshape(-1))        # rows by expert
         xs = x.reshape(B * S, D)[order // k]           # (R, D) sorted
     with jax.named_scope("moe.gmm"):
         h = jax.nn.silu(ragged_matmul(xs, p["wi_gate"], counts)) \
             * ragged_matmul(xs, p["wi_up"], counts)
         ys = ragged_matmul(h, p["wo"], counts)         # (R, D) sorted
     with jax.named_scope("moe.combine"):
-        if held < n_experts:
-            # the rows past the held experts' are in no group: not computed
-            # (the kernel leaves them unwritten) and not added
-            ys = jnp.where((jnp.arange(R) < counts.sum())[:, None], ys, 0)
-        unsort = jnp.zeros((R,), jnp.int32).at[order].set(
-            jnp.arange(R, dtype=jnp.int32))
-        y = ys[unsort].reshape(B, S, k, D).astype(jnp.float32)
-        out = (y * gate_vals[..., None]).sum(axis=2).astype(x.dtype)
-    return out, counts
+        # rows past the held experts' are in no group: not computed (the
+        # grouped matmul leaves them unwritten), not read and not added
+        out = moe_combine(ys, pos, gate_vals.reshape(B * S, k), bounds)
+    return out.reshape(B, S, D), counts

@@ -64,7 +64,15 @@ _PHASES = textwrap.dedent("""
                                 ((4,), "int32")),
              "moe_ragged_gmm_mixtral": (((160, 512), "bfloat16"),
                                         ((4, 512, 256), "bfloat16"),
-                                        ((4,), "int32"))}
+                                        ((4,), "int32")),
+             "moe_combine_granite": (((2048, 128), "bfloat16"),
+                                     ((256, 8), "int32"),
+                                     ((256, 8), "float32"),
+                                     ((3, 32), "int32")),
+             "moe_combine_granite4h": (((2560, 256), "bfloat16"),
+                                       ((256, 10), "int32"),
+                                       ((256, 10), "float32"),
+                                       ((3, 9), "int32"))}
     for name in ("ring_decode_granite", "ring_decode_qwen3"):
         small[name] = (((2, 4, 128), "bfloat16"),
                        ((2, 2, 64, 128), "bfloat16"),

@@ -9,6 +9,8 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.mamba2_ssd.ops import ssd
 from repro.kernels.mamba2_ssd.ref import ssd_ref
+from repro.kernels.moe_combine.ops import group_rows, moe_combine
+from repro.kernels.moe_combine.ref import moe_combine_ref
 from repro.kernels.moe_gmm.ops import ragged_matmul
 from repro.kernels.moe_gmm.ref import ragged_ref
 from repro.kernels.ring_decode.kernel import block_slots
@@ -104,6 +106,51 @@ def test_ragged_gmm_sweep(sizes, K, N, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32) / scale,
                                np.asarray(want, np.float32) / scale,
                                atol=tol)
+
+
+def _routing(key, T, k, n_experts, layout):
+    """(T, k) distinct experts a token.  ``fill_empty``: every token of the
+    first 128-token tile picks expert 0, no token of the second does."""
+    score = jax.random.uniform(key, (T, n_experts))
+    if layout == "fill_empty":
+        t = jnp.arange(T)[:, None]
+        first = jnp.arange(n_experts)[None] == 0
+        score = jnp.where(first & (t < 128), -1.0, score)
+        score = jnp.where(first & (t >= 128) & (t < 256), 2.0, score)
+    return jnp.argsort(score, axis=1)[:, :k].astype(jnp.int32)
+
+
+@pytest.mark.parametrize("T,k,n_experts,held,D,layout", [
+    (256, 8, 32, 32, 128, "uniform"),     # every expert held, k = 8
+    (256, 10, 72, 9, 256, "uniform"),     # 9 of 72 held, k = 10
+    (200, 8, 32, 32, 128, "uniform"),     # tokens past the last whole tile
+    (300, 10, 72, 9, 128, "uniform"),     # both
+    (384, 8, 32, 32, 128, "fill_empty"),  # an expert in every / no token
+    (128, 2, 64, 1, 128, "uniform"),      # fewer held rows than a chunk
+], ids=["all_held_k8", "share_k10", "ragged_tokens", "share_ragged",
+        "fill_and_empty", "few_rows"])
+def test_moe_combine_sweep(T, k, n_experts, held, D, layout):
+    """The combine kernel (interpreted) against the oracle.  Rows past the
+    held experts' (never computed) are NaN and must not reach the output;
+    the sums differ from the oracle's only in their order, so by at most a
+    bfloat16 rounding step."""
+    ks = jax.random.split(KEY, 3)
+    experts = _routing(ks[0], T, k, n_experts, layout)
+    gates = jax.random.uniform(ks[1], (T, k), jnp.float32)
+    counts, pos, bounds = group_rows(experts, held)
+    n = int(counts.sum())
+    ys = jax.random.normal(ks[2], (T * k, D), jnp.bfloat16)
+    ys = ys.at[n:].set(jnp.nan)
+    if layout == "fill_empty":
+        per_tile = np.diff(np.asarray(bounds)[:, 0])
+        assert per_tile[0] == 128 and per_tile[1] == 0
+    got = moe_combine(ys, pos, gates, bounds, impl="interpret")
+    want = moe_combine_ref(ys, pos, gates)
+    assert got.shape == (T, D) and got.dtype == jnp.bfloat16
+    assert not np.isnan(np.asarray(want, np.float32)).any()
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2 ** -7, atol=2 ** -10)
 
 
 @pytest.mark.parametrize("N,B,T,C,R,n", [

@@ -26,6 +26,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.kernels.moe_combine.kernel import TILE
+from repro.kernels.moe_combine.ops import group_rows
 from repro.kernels.moe_gmm.ops import ragged_matmul, row_tile
 from repro.models import decode_step, prefill
 from repro.models.moe import moe_mlp, moe_params
@@ -138,6 +140,86 @@ def test_ragged_dot_matches_megablox(sizes):
     grp = np.repeat(np.arange(len(sizes)), sizes)
     want = np.einsum("md,mdn->mn", np.asarray(x), np.asarray(w)[grp])
     np.testing.assert_allclose(np.asarray(ref), want, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("T,k,n_experts,held,astray", [
+    (127, 2, 8, 8, False),        # every expert held, a row short of a tile
+    (300, 8, 32, 32, False),      # tokens past the last whole tile
+    (256, 10, 72, 9, False),      # a share of the experts, k = 10
+    (129, 2, 8, 8, True),         # ids past the last expert, a row past
+], ids=["8x2", "granite_32x8_ragged", "share_72x10", "astray"])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_group_rows_equal_bincount_and_sort_inverse(T, k, n_experts, held,
+                                                    astray, seed):
+    """``group_rows``'s scatter-free group sizes, positions and slab table
+    on random routings: the sizes are ``bincount``'s over the held experts,
+    each row's position is where the stable sort by expert puts it (the
+    inverse of ``argsort``), -1 for rows in no group, and the table holds
+    the first row of each group in each of the kernel's token tiles."""
+    ks = jax.random.split(jax.random.key(seed), 2)
+    experts = jnp.argsort(jax.random.uniform(ks[0], (T, n_experts)),
+                          axis=1)[:, :k].astype(jnp.int32)
+    if astray:
+        experts = jnp.where(jax.random.uniform(ks[1], (T, k)) < 0.1,
+                            n_experts + 3, experts)
+    counts, pos, bounds = group_rows(experts, held)
+    flat = np.asarray(experts).ravel()
+    inside = flat < held
+    want = np.bincount(flat[inside], minlength=held)
+    np.testing.assert_array_equal(np.asarray(counts), want)
+    order = np.argsort(flat, kind="stable")
+    inverse = np.empty_like(order)
+    inverse[order] = np.arange(flat.size)
+    got = np.asarray(pos).ravel()
+    np.testing.assert_array_equal(got[inside], inverse[inside])
+    assert (got[~inside] == -1).all()
+    starts = np.concatenate([[0], np.cumsum(want)[:-1]])
+    tok = np.repeat(np.arange(T), k)
+    table = np.asarray(bounds)
+    assert table.shape == (-(-T // TILE) + 1, held)
+    for i in range(table.shape[0]):
+        before = np.bincount(flat[inside & (tok < i * TILE)],
+                             minlength=held)
+        np.testing.assert_array_equal(table[i], starts + before)
+
+
+@pytest.mark.parametrize("held,first,S", [
+    (8, 0, 64),         # every expert held, whole token tiles
+    (3, 2, 50),         # experts 2..4 of 8 held, tokens past a tile
+], ids=["all_held", "share_ragged"])
+def test_dropless_grad_through_combine_kernel_equals_oracle(held, first, S,
+                                                            monkeypatch):
+    """A training step differentiates through the combine kernel: the
+    gradients of ``moe_mlp`` (router, experts, input) with the kernel
+    (interpreted) equal the oracle's, in bfloat16 as a TPU runs it."""
+    import functools
+
+    from repro.kernels.moe_combine import ops as combine
+    from repro.models import moe
+
+    p, x = _moe_inputs(False, seed=11, S=S)
+    p = {n: v if n == "router" else v[first:first + held].astype(
+        jnp.bfloat16) for n, v in p.items()}
+    x = x.astype(jnp.bfloat16)
+    ct = jax.random.normal(jax.random.key(12), x.shape, jnp.bfloat16)
+
+    def grads(impl):
+        monkeypatch.setattr(moe, "moe_combine",
+                            functools.partial(combine.moe_combine, impl=impl))
+
+        def loss(p, x):
+            out, _ = moe_mlp(p, x, n_experts=E, top_k=K, first_expert=first)
+            return jnp.sum(out.astype(jnp.float32) * ct)
+
+        return jax.grad(loss, argnums=(0, 1))(p, x)
+
+    got, want = grads("interpret"), grads("ref")
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        w = np.asarray(w, np.float32)
+        assert np.abs(w).max() > 0
+        np.testing.assert_allclose(np.asarray(g, np.float32), w,
+                                   rtol=2 ** -7,
+                                   atol=2 ** -7 * np.abs(w).max())
 
 
 def test_row_tile():

@@ -138,3 +138,104 @@ def test_hybrid_decode_takes_its_cache_in_place_for_v5e(one_chip,
     total = sum(nbytes(a) for a in jax.tree.leaves(cache))
     assert 0 <= mem.alias_size_in_bytes - total <= 512
     assert mem.temp_size_in_bytes < nbytes(state) // state.shape[0]
+
+
+# _dropless's temporaries at each cell's prefill, compiled for a described
+# v5e with the unsort scatter, the (R, D) gather and its float32 copy of the
+# combine it had before the combine kernel
+PARENT_TEMP = {"granite-moe-1b-a400m": 1611257856,
+               "granite-4.0-h-small": 5637692928}
+
+
+@pytest.mark.parametrize("arch,slots,fall", [
+    ("granite-moe-1b-a400m", 32, 500 << 20),
+    ("granite-4.0-h-small", 16, 3 << 30),
+])
+def test_dropless_prefill_combine_copies_nothing_for_v5e(arch, slots, fall,
+                                                         one_chip,
+                                                         monkeypatch):
+    """The dropless MoE at a cell's prefill (``slots`` lanes of 1024
+    tokens) on a v5e: no scatter under ``moe.route`` or ``moe.combine``
+    (group sizes and sorted positions come from a cumulative sum), no (R, D)
+    result under ``moe.combine`` (the kernel reads the expert rows where the
+    grouped matmul wrote them), and temporaries ``fall`` bytes or more
+    below the old combine's.  The peak is now the grouped matmuls': the
+    sorted rows and the gate and up projections' outputs, live together."""
+    import functools
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.kernels.moe_combine import ops as combine
+    from repro.kernels.moe_gmm import ops as gmm
+    from repro.models import moe
+
+    monkeypatch.setattr(moe, "ragged_matmul",
+                        functools.partial(gmm.ragged_matmul, impl="pallas"))
+    monkeypatch.setattr(moe, "moe_combine",
+                        functools.partial(combine.moe_combine, impl="pallas"))
+    cfg = get_config(arch)
+    B, S, D, F = slots, 1024, cfg.d_model, cfg.moe_d_ff
+    E, k, held = cfg.n_experts, cfg.n_experts_active, cfg.n_experts_held
+    R = B * S * k
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    bank = {"wi_gate": spec((held, D, F), jnp.bfloat16),
+            "wi_up": spec((held, D, F), jnp.bfloat16),
+            "wo": spec((held, F, D), jnp.bfloat16)}
+    compiled = jax.jit(lambda p, x, g, e: moe._dropless(p, x, g, e, E)).lower(
+        bank, spec((B, S, D), jnp.bfloat16), spec((B, S, k), jnp.float32),
+        spec((B, S, k), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    wide = re.compile(rf"= (f32|bf16)\[{R},{D}\]")
+    for line in text.splitlines():
+        scope = re.search(r'op_name="[^"]*(moe\.route|moe\.combine)/', line)
+        if not scope:
+            continue
+        op = line.split("metadata=")[0]
+        assert not re.search(r"\bscatter(-add)?\(", op), line
+        if scope.group(1) == "moe.combine":
+            assert not wide.search(op), line
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= PARENT_TEMP[arch] - fall
+    assert temp < (R * D + 2 * R * F) * 2 + (16 << 20)
+
+
+def test_dropless_train_step_differentiates_the_combine_kernel_for_v5e(
+        one_chip, monkeypatch):
+    """A training step's gradient through the dropless MoE at granite-moe's
+    widths compiles for a v5e with the combine kernel in its forward: the
+    kernel's gradients are XLA ops (a Pallas call with scalar prefetch has
+    no derivative of its own)."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.kernels.moe_combine import ops as combine
+    from repro.kernels.moe_gmm import ops as gmm
+    from repro.models import moe
+
+    monkeypatch.setattr(moe, "ragged_matmul",
+                        functools.partial(gmm.ragged_matmul, impl="pallas"))
+    monkeypatch.setattr(moe, "moe_combine",
+                        functools.partial(combine.moe_combine, impl="pallas"))
+    cfg = get_config("granite-moe-1b-a400m")
+    B, S, D, F = 2, 512, cfg.d_model, cfg.moe_d_ff
+    E, k = cfg.n_experts, cfg.n_experts_active
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    p = {"router": spec((D, E), jnp.float32),
+         "wi_gate": spec((E, D, F), jnp.bfloat16),
+         "wi_up": spec((E, D, F), jnp.bfloat16),
+         "wo": spec((E, F, D), jnp.bfloat16)}
+
+    def loss(p, x):
+        out, _ = moe.moe_mlp(p, x, n_experts=E, top_k=k)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))  # needs out
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        p, spec((B, S, D), jnp.bfloat16)).compile()
+    assert "moe_combine_fwd" in compiled.as_text()
